@@ -60,6 +60,7 @@ from repro.core.analyzer import (
 )
 from repro.core.claims import ClaimMode
 from repro.core.native_descriptor import default_engine_factory
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.chaos import (
     FaultPlan,
     FaultSpec,
@@ -415,4 +416,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

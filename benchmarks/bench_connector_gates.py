@@ -30,6 +30,7 @@ from repro.core.analyzer import (
 from repro.core.claims import ClaimMode
 from repro.core.events import EventLog
 from repro.core.native_descriptor import PREFIX, default_engine_factory
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.offload import FailureInjectionConfig
 
 
@@ -206,4 +207,5 @@ def run_gates(out_dir: Path = Path("results/connector_gates")) -> Dict[str, str]
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     print(json.dumps(run_gates(), indent=1))

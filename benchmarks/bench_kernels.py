@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _time(fn, *args, iters=3):
@@ -65,5 +66,6 @@ def run(out_path: Path = Path("results/kernel-bench.json")):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     for r in run():
         print(f"{r['name']},{r['pallas_interpret_us']:.1f},max_err={r['max_err']:.2e}")

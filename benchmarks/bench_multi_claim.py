@@ -40,6 +40,7 @@ from pathlib import Path
 from repro.core.analyzer import check_multi_claim_attribution, validate_event_sequence
 from repro.core.claims import ClaimMode, ClaimState
 from repro.core.native_descriptor import default_engine_factory
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run(out_path: Path = Path("results/vllm-multi-claim-attribution-control.json"), make_engine=None):
@@ -369,6 +370,7 @@ def run_prefill_ceiling(out_path: Path = Path("results/BENCH_serving.json")):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     fast = "--fast" in sys.argv
     make_engine = default_engine_factory()
     print(json.dumps(run(make_engine=make_engine), indent=1))

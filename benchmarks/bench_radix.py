@@ -55,6 +55,7 @@ from repro.core.analyzer import (
     validate_event_sequence,
 )
 from repro.core.native_descriptor import default_engine_factory
+from repro.launch.compile_cache import enable_compile_cache
 
 CAPACITY_RATIO_MIN = 1.5
 ENGINE_KW = dict(device_blocks=48, cache_len=64)
@@ -226,4 +227,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -57,6 +57,7 @@ from repro.core.analyzer import (
     validate_event_sequence,
 )
 from repro.core.native_descriptor import default_engine_factory
+from repro.launch.compile_cache import enable_compile_cache
 
 ITL_RATIO_MAX = 1.5
 N_STREAMS = 10
@@ -294,4 +295,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

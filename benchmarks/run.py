@@ -14,6 +14,8 @@ import sys
 import time
 from pathlib import Path
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _section(name):
     print(f"\n== {name} " + "=" * max(0, 60 - len(name)))
@@ -143,4 +145,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

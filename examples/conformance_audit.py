@@ -6,6 +6,7 @@ lowering matrix (the paper's central result, §8.1).
 """
 from repro.core.checker import generate_matrix, write_outputs
 from repro.core.native_descriptor import generate_native_descriptor
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -25,4 +26,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -8,6 +8,7 @@ import jax
 from repro.configs import get_config, reduced
 from repro.core.analyzer import check_observation_path, validate_event_sequence
 from repro.core.claims import ClaimMode, ClaimState
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serving.engine import ServingEngine
 
@@ -48,4 +49,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

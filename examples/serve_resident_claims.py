@@ -12,6 +12,7 @@ from repro.core.analyzer import (
     check_multi_claim_attribution,
 )
 from repro.core.claims import ClaimMode, ClaimState
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import build_model
 from repro.serving.engine import ServingEngine
 from repro.serving.router import KVAwareRouter
@@ -99,4 +100,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

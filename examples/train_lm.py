@@ -17,6 +17,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_debug_mesh
 from repro.models.registry import build_model
 from repro.training.data import DataConfig
@@ -81,4 +82,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -64,6 +64,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# every gate below is a CPU number (Pallas in interpret mode); the chip run
+# is chip_smoke.py, which refuses to run without a TPU
+export JAX_PLATFORMS=cpu
 
 echo "== tier-1: pytest (full suite, checker included) =="
 python -m pytest -x -q

@@ -51,10 +51,15 @@ def gather_payloads(arrays: Sequence[np.ndarray], *, interpret: Optional[bool] =
     The transfer backend's multi-block jobs land here: instead of N separate
     per-block copies, the payloads are stacked into a [N, page, KV, D] slab
     and gathered in a single ``kv_block_copy`` launch (one grid, Mosaic
-    double-buffers consecutive pages).  Payloads whose shapes cannot form a
-    uniform 4-D page layout (e.g. packed state snapshots) fall back to a
-    plain per-array copy — the batching is an optimization, never a
-    correctness dependency.
+    double-buffers consecutive pages).
+
+    Whether the kernel runs is decided from the shapes, before the launch:
+    only uniform page payloads (one shape and dtype, non-empty, at least
+    3-D, so the trailing [KV, D] dims tile as a page) go through it.
+    Anything else (e.g. packed 1-D state snapshots, whose (size, 1, 1)
+    page would pad every element to a full tile on the TPU) takes a plain
+    per-array copy.  A kernel failure propagates to the caller — the
+    transfer job — and is never papered over with a copy.
 
     Returns freshly materialized numpy arrays in input order.
     """
@@ -63,21 +68,17 @@ def gather_payloads(arrays: Sequence[np.ndarray], *, interpret: Optional[bool] =
     arrays = [np.asarray(a) for a in arrays]
     if not arrays:
         return []
-    shapes = {(a.shape, a.dtype.str) for a in arrays}
-    uniform = len(shapes) == 1 and arrays[0].size > 0
-    if uniform:
-        first = arrays[0]
-        # page layout: flatten leading dims so every payload is one page
-        if first.ndim >= 3:
-            page_shape = (int(np.prod(first.shape[:-2])), first.shape[-2], first.shape[-1])
-        else:
-            page_shape = (first.size, 1, 1)
-        try:
-            src = jnp.asarray(np.stack([a.reshape(page_shape) for a in arrays]))
-            idx = jnp.arange(len(arrays), dtype=jnp.int32)
-            out = ops.kv_block_copy(src, idx, interpret=interpret)
-            out = np.asarray(out)
-            return [out[i].reshape(arrays[i].shape) for i in range(len(arrays))]
-        except Exception:  # unsupported dtype/layout: fall through to copies
-            pass
-    return [np.array(a, copy=True) for a in arrays]
+    first = arrays[0]
+    uniform_pages = (
+        len({(a.shape, a.dtype.str) for a in arrays}) == 1
+        and first.size > 0
+        and first.ndim >= 3
+    )
+    if not uniform_pages:
+        return [np.array(a, copy=True) for a in arrays]
+    # page layout: flatten leading dims so every payload is one page
+    page_shape = (int(np.prod(first.shape[:-2])), first.shape[-2], first.shape[-1])
+    src = jnp.asarray(np.stack([a.reshape(page_shape) for a in arrays]))
+    idx = jnp.arange(len(arrays), dtype=jnp.int32)
+    out = np.asarray(ops.kv_block_copy(src, idx, interpret=interpret))
+    return [out[i].reshape(a.shape) for i, a in enumerate(arrays)]

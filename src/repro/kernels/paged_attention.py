@@ -151,7 +151,7 @@ def _paged_decode_kernel(
     v_ref,
     kt_ref,  # [1, 1, T, D]  in-flight tail
     vt_ref,
-    tp_ref,  # [1, T] int32  absolute tail positions (-1 = empty)
+    tp_ref,  # [1, 1, T] int32  absolute tail positions (-1 = empty)
     o_ref,  # [1, 1, G, D]
     acc_ref,
     m_ref,
@@ -213,7 +213,7 @@ def _paged_decode_kernel(
         ) * sm_scale  # [G, T]
         if softcap:
             s = softcap * jnp.tanh(s / softcap)
-        tp = tp_ref[0, :][None, :]  # [1, T]
+        tp = tp_ref[0]  # [1, T]
         valid = (tp >= 0) & (tp <= cur_pos)
         if window:
             valid &= cur_pos - tp < window
@@ -462,7 +462,10 @@ def paged_decode_attention_pallas(
             pl.BlockSpec((1, 1, page_size, D), lambda b, kv, p, bt, ln, cp: (kv, bt[b, p], 0, 0)),
             pl.BlockSpec((1, 1, T, D), lambda b, kv, p, bt, ln, cp: (b, kv, 0, 0)),
             pl.BlockSpec((1, 1, T, D), lambda b, kv, p, bt, ln, cp: (b, kv, 0, 0)),
-            pl.BlockSpec((1, T), lambda b, kv, p, bt, ln, cp: (b, 0)),
+            # [B, 1, T]: a (1, 1, T) block keeps its last two dims equal to
+            # the array's, as Mosaic's tiling rule requires for any B (a
+            # (1, T) block over [B, T] compiles only when B == 1)
+            pl.BlockSpec((1, 1, T), lambda b, kv, p, bt, ln, cp: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, kv, p, bt, ln, cp: (b, kv, 0, 0)),
         scratch_shapes=[
@@ -485,5 +488,5 @@ def paged_decode_attention_pallas(
         v_pages,
         k_tail,
         v_tail,
-        tail_pos.astype(jnp.int32),
+        tail_pos.astype(jnp.int32).reshape(B, 1, T),
     )

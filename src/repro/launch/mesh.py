@@ -10,14 +10,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh across JAX versions: the ``axis_types`` kwarg (and the
-    ``jax.sharding.AxisType`` enum backing it) only exists on newer JAX; on
-    0.4.x every axis is implicitly Auto, so calling without it is
-    equivalent."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -52,6 +52,7 @@ TRIGGER_WORKER_DEATH = "worker_death"
 TRIGGER_CAPACITY = "capacity_pressure"
 TRIGGER_QUARANTINE = "tier_quarantined"
 TRIGGER_INJECTED = "injected_load_failure"  # legacy FailureInjectionConfig
+TRIGGER_TRANSFER_ERROR = "transfer_error"  # job fn raised (e.g. copy kernel)
 
 FAULT_TRIGGERS = (
     TRIGGER_TRANSIENT,

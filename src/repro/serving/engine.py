@@ -304,7 +304,8 @@ class ServingEngine(EngineCore):
         just the dirty slots is NOT profitable here: without buffer
         donation ``.at[].set`` copies the whole mirror, and donation is
         unsound because live decode states alias these arrays.  On the TPU
-        target the page store IS device memory and this is the identity."""
+        too the page store is this host mirror, re-uploaded whole after any
+        page write — the pool is not yet device-resident."""
         pool = self.pool
         ver = pool._pages_version if pool.k_pages is not None else -1
         if self._pages_mirror is None or self._pages_mirror[0] != ver:

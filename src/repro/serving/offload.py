@@ -31,6 +31,10 @@ kept and classified as ``injected_load_failure``):
     and the engine-side join converts ``TransferWorkerDied`` into the same
     ordered fail-closed path (E4 fail + E11 emitted at the join, still
     strictly before any lifecycle event).
+  - **transfer_error**: any other exception out of the job fn (e.g. the
+    ``kv_block_copy`` kernel failing on the device) is carried to the join
+    the same way and fails the job with that trigger — the claim fails
+    closed with attribution, the error text in the reason.
   - **checksum verification**: every restored payload is verified against
     the checksum written at first spill (tiers.py); a mismatch is a
     ``corruption`` failure — the bytes never reach the device pool.
@@ -58,6 +62,7 @@ from repro.serving.chaos import (
     TRIGGER_CORRUPTION,
     TRIGGER_INJECTED,
     TRIGGER_QUARANTINE,
+    TRIGGER_TRANSFER_ERROR,
     TRIGGER_TRANSIENT_EXHAUSTED,
     TRIGGER_WORKER_DEATH,
 )
@@ -559,6 +564,10 @@ class OffloadingConnector:
         except TransientTransferFault as e:  # queue's runaway backstop
             self._job_fault_at_join(
                 job, e.block_id, e.direction, str(e), TRIGGER_TRANSIENT_EXHAUSTED
+            )
+        except Exception as e:  # noqa: BLE001 — e.g. the copy kernel raised
+            self._job_fault_at_join(
+                job, None, "", f"{type(e).__name__}: {e}", TRIGGER_TRANSFER_ERROR
             )
         self._update_tier_gauges()
 

@@ -39,6 +39,7 @@ from repro.serving.chaos import (
     TRIGGER_PERMANENT,
     TRIGGER_QUARANTINE,
     TRIGGER_TRANSIENT,
+    TRIGGER_TRANSFER_ERROR,
     TRIGGER_TRANSIENT_EXHAUSTED,
     TRIGGER_WORKER_DEATH,
 )
@@ -338,6 +339,36 @@ def test_worker_death_is_claim_refusal_and_engine_survives(kv):
     # the engine's transfer path is still serviceable after the death
     other = tuple(range(300, 316))
     c2 = _offloaded_claim(eng, prefix=other, tier="disk")
+    r2 = eng.submit(other + (40, 41), max_new_tokens=1)
+    eng.run(r2)
+    assert r2.status == "finished" and c2.state == ClaimState.RESTORED
+    assert validate_event_sequence(eng.events).passed
+    eng.close()
+
+
+def test_copy_kernel_failure_is_attributed_claim_refusal(kv, monkeypatch):
+    """A restore whose kv_block_copy launch raises is no silent copy: the
+    transfer job fails with trigger ``transfer_error`` and the claim takes
+    the ordered fail-closed path; the engine keeps serving afterwards."""
+    from repro.kernels import ops
+
+    eng = kv(quarantine_after=None)
+    claim = _offloaded_claim(eng)
+
+    def broken_copy(*_a, **_kw):
+        raise RuntimeError("copy kernel failed")
+
+    monkeypatch.setattr(ops, "kv_block_copy", broken_copy)
+    r = eng.submit(PREFIX + (40, 41), max_new_tokens=1)
+    eng.run(r)
+    assert r.status == "refused" and "copy kernel failed" in r.error
+    assert r.output_tokens == []
+    assert claim.state == ClaimState.RESTORATION_FAILED
+    assert eng.fail_closed_total() == {TRIGGER_TRANSFER_ERROR: 1}
+    assert check_fail_closed_attribution(eng.events).passed
+    monkeypatch.undo()
+    other = tuple(range(300, 316))
+    c2 = _offloaded_claim(eng, prefix=other)
     r2 = eng.submit(other + (40, 41), max_new_tokens=1)
     eng.run(r2)
     assert r2.status == "finished" and c2.state == ClaimState.RESTORED

@@ -312,5 +312,48 @@ def test_kv_block_copy_matches_ref(dtype):
     out = ops.kv_block_copy(src, idx)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref.kv_block_copy_ref(src, idx)))
 
+
+def test_gather_payloads_surfaces_kernel_failure(monkeypatch):
+    """Uniform page payloads go through the copy kernel, and a kernel
+    failure reaches the caller — no silent numpy copy in its place."""
+    from repro.kernels.kv_block_copy import gather_payloads
+
+    rng = np.random.default_rng(10)
+    pages = [rng.normal(size=(2, 4, 2, 8)).astype(np.float32) for _ in range(3)]
+    got = gather_payloads(pages)
+    for a, b in zip(got, pages):
+        np.testing.assert_array_equal(a, b)
+        assert a is not b
+
+    def broken_copy(*_a, **_kw):
+        raise RuntimeError("copy kernel failed")
+
+    monkeypatch.setattr(ops, "kv_block_copy", broken_copy)
+    with pytest.raises(RuntimeError, match="copy kernel failed"):
+        gather_payloads(pages)
+
+
+@pytest.mark.parametrize(
+    "shapes",
+    [
+        [(64,), (64,)],  # packed 1-D state snapshots
+        [(2, 4, 2, 8), (2, 4, 2, 4)],  # mixed shapes
+        [(2, 0, 2, 8)],  # empty
+    ],
+)
+def test_gather_payloads_non_pages_take_plain_copy(monkeypatch, shapes):
+    """Payloads that are not uniform pages are copied without a launch."""
+    from repro.kernels.kv_block_copy import gather_payloads
+
+    def no_launch(*_a, **_kw):
+        raise AssertionError("non-page payloads must not launch the kernel")
+
+    monkeypatch.setattr(ops, "kv_block_copy", no_launch)
+    arrays = [np.arange(int(np.prod(s)), dtype=np.float32).reshape(s) for s in shapes]
+    got = gather_payloads(arrays)
+    for a, b in zip(got, arrays):
+        np.testing.assert_array_equal(a, b)
+        assert a is not b
+
 # Property tests (hypothesis) live in tests/test_hypothesis_properties.py so
 # this module always collects even when hypothesis is absent.
