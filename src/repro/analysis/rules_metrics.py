@@ -32,7 +32,6 @@ _RECONCILE_HELPERS = frozenset({"_counter_series", "_histogram_counts"})
 # carries the reason; a stale entry (family reconciled after all, or no
 # longer registered) is itself a finding.
 EXEMPT: Dict[str, str] = {
-    "scheduler_step_occupancy": "gauge: last-step load factor, point-in-time by design",
     "tier_blocks": "gauge: point-in-time tier occupancy, no event witness",
     "tier_bytes": "gauge: point-in-time tier occupancy, no event witness",
     "tier_quarantined": "gauge: current quarantine flag; the transition is the "
@@ -47,8 +46,6 @@ EXEMPT: Dict[str, str] = {
     "engine-visible mirror transfer_retries_total IS reconciled (rule 4)",
     "chaos_faults_injected_total": "plan ground truth: reconciled against the "
     "FaultPlan counters in bench_chaos, not the event log",
-    "pages_shared": "gauge: point-in-time count of device pages with more than "
-    "one live reference, no event witness",
 }
 
 

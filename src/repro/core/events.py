@@ -83,7 +83,9 @@ NATIVE_EVENTS = (
     # marks one unified scheduler step (engine-scoped, request_id=None so
     # per-request projections stay byte-identical across batch compositions)
     # carrying the step's token accounting: decode/feed rows + at most one
-    # in-flight prefill chunk under the max_tokens_per_step budget
+    # in-flight prefill chunk under the max_tokens_per_step budget, and the
+    # KV bytes its loop iteration copied host->device (upload_bytes) and
+    # device->host (readback_bytes)
     "batch_scheduled",
     "step_scheduled",
     # fault handling (serving/chaos.py, serving/offload.py): a bounded
@@ -182,6 +184,8 @@ PAYLOAD_SCHEMA: Dict[str, frozenset] = {
             "prefill_tokens",
             "step_tokens",
             "budget",
+            "upload_bytes",
+            "readback_bytes",
         }
     ),
     "transfer_retry_scheduled": frozenset(

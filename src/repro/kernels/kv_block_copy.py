@@ -37,12 +37,13 @@ def kv_block_copy_pallas(src_pages, indices, *, interpret: bool = False):
         ],
         out_specs=pl.BlockSpec((1, page, KV, D), lambda m, idx: (m, 0, 0, 0)),
     )
-    return pl.pallas_call(
-        _copy_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, page, KV, D), src_pages.dtype),
-        interpret=interpret,
-    )(indices, src_pages)
+    with jax.named_scope("kv_block_copy"):
+        return pl.pallas_call(
+            _copy_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((M, page, KV, D), src_pages.dtype),
+            interpret=interpret,
+        )(indices, src_pages)
 
 
 def gather_payloads(arrays: Sequence[np.ndarray], *, interpret: Optional[bool] = None) -> List[np.ndarray]:

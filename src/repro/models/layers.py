@@ -533,9 +533,10 @@ def attn_paged_decode_layer(
     """
     B = x.shape[0]
     q, k, v = attn_qkv(p, cfg, x, cur_pos[:, None], use_rope=use_rope)
-    new_tk = slot_update(tail_k, k, tail_slot)
-    new_tv = slot_update(tail_v, v, tail_slot)
-    new_tk, new_tv = jax.lax.optimization_barrier((new_tk, new_tv))
+    with jax.named_scope("tail_write"):
+        new_tk = slot_update(tail_k, k, tail_slot)
+        new_tv = slot_update(tail_v, v, tail_slot)
+        new_tk, new_tv = jax.lax.optimization_barrier((new_tk, new_tv))
     out = paged_attention_decode(
         q, k_pages, v_pages, block_tables, prefix_len,
         new_tk, new_tv, tail_pos, cur_pos,

@@ -301,7 +301,8 @@ def prefill_chunk(params, cfg, state, tokens, positions, *, mesh=None, moe_strat
     """
     from repro.models.layers import attn_paged_prefill_layer
 
-    x = embed_tokens(params, cfg, tokens)  # [B, C, d]
+    with jax.named_scope("embed"):
+        x = embed_tokens(params, cfg, tokens)  # [B, C, d]
     bt = state["block_tables"]
     plen = state["prefix_len"]
 
@@ -309,17 +310,19 @@ def prefill_chunk(params, cfg, state, tokens, positions, *, mesh=None, moe_strat
         x, = carry
         lp, kp, vp = xs
         x = constrain_activations(x, mesh)
-        h = apply_norm(cfg.norm, lp["ln1"], x)
-        a, (k_, v_) = attn_paged_prefill_layer(
-            lp["attn"], cfg, h, kp, vp, bt, plen, positions
-        )
-        x = x + a
-        h = apply_norm(cfg.norm, lp["ln2"], x)
-        if cfg.moe.num_experts:
-            m, _ = _moe_block(lp, cfg, h, mesh, moe_strategy)
-        else:
-            m = mlp_apply(lp["mlp"], h, cfg.activation)
-        x = x + m
+        with jax.named_scope("attention"):
+            h = apply_norm(cfg.norm, lp["ln1"], x)
+            a, (k_, v_) = attn_paged_prefill_layer(
+                lp["attn"], cfg, h, kp, vp, bt, plen, positions
+            )
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg.norm, lp["ln2"], x)
+            if cfg.moe.num_experts:
+                m, _ = _moe_block(lp, cfg, h, mesh, moe_strategy)
+            else:
+                m = mlp_apply(lp["mlp"], h, cfg.activation)
+            x = x + m
         x = constrain_activations(x, mesh)
         return (x,), (constrain_activations(k_, mesh), constrain_activations(v_, mesh))
 
@@ -395,29 +398,33 @@ def paged_decode_step(params, cfg, state, tokens, cur_pos, *, mesh=None, moe_str
 
     from repro.models.layers import attn_paged_decode_layer, slot_update as _slot_update
 
-    x = params["embed"][tokens][:, None, :]  # [B, 1, d]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens][:, None, :]  # [B, 1, d]
     slot = cur_pos - state["prefix_len"]
-    tail_pos = _slot_update(
-        state["tail_pos"][..., None], cur_pos[:, None, None], slot
-    )[..., 0]
+    with jax.named_scope("tail_write"):
+        tail_pos = _slot_update(
+            state["tail_pos"][..., None], cur_pos[:, None, None], slot
+        )[..., 0]
 
     def body(carry, xs):
         x, = carry
         lp, kp, vp, tk, tv = xs
         x = constrain_activations(x, mesh, seq_dim=None)
-        h = apply_norm(cfg.norm, lp["ln1"], x)
-        a, ntk, ntv = attn_paged_decode_layer(
-            lp["attn"], cfg, h, kp, vp,
-            state["block_tables"], state["prefix_len"],
-            tk, tv, tail_pos, cur_pos, slot,
-        )
-        x = x + a
-        h = apply_norm(cfg.norm, lp["ln2"], x)
-        if cfg.moe.num_experts:
-            m, _ = _moe_block(lp, cfg, h, mesh, moe_strategy)
-        else:
-            m = mlp_apply(lp["mlp"], h, cfg.activation)
-        x = x + m
+        with jax.named_scope("attention"):
+            h = apply_norm(cfg.norm, lp["ln1"], x)
+            a, ntk, ntv = attn_paged_decode_layer(
+                lp["attn"], cfg, h, kp, vp,
+                state["block_tables"], state["prefix_len"],
+                tk, tv, tail_pos, cur_pos, slot,
+            )
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = apply_norm(cfg.norm, lp["ln2"], x)
+            if cfg.moe.num_experts:
+                m, _ = _moe_block(lp, cfg, h, mesh, moe_strategy)
+            else:
+                m = mlp_apply(lp["mlp"], h, cfg.activation)
+            x = x + m
         return (x,), (constrain_activations(ntk, mesh), constrain_activations(ntv, mesh))
 
     (x,), (ntk, ntv) = jax.lax.scan(
@@ -425,8 +432,9 @@ def paged_decode_step(params, cfg, state, tokens, cur_pos, *, mesh=None, moe_str
         (x,),
         (params["layers"], state["k_pages"], state["v_pages"], state["k_tail"], state["v_tail"]),
     )
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    logits = (x[:, 0] @ unembed(cfg, params)).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = apply_norm(cfg.norm, params["final_norm"], x)
+        logits = (x[:, 0] @ unembed(cfg, params)).astype(jnp.float32)
     new_state = dict(state, k_tail=ntk, v_tail=ntv, tail_pos=tail_pos)
     return logits, new_state
 

@@ -22,9 +22,10 @@ from __future__ import annotations
 
 import itertools
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from functools import lru_cache
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -73,6 +74,20 @@ class Request:
     # monotonic wall-clock when the FIRST output token was appended (TTFT
     # measurement surface for the step scheduler and bench_scheduler)
     first_token_ts: Optional[float] = None
+    # monotonic wall-clock just before the first launch that carried this
+    # request's tokens (its first prefill chunk or collect launch, or a
+    # continuation's first mixed step): the end of its queue wait, on
+    # first_token_ts's clock
+    scheduled_ts: Optional[float] = None
+
+
+class _LoadFailed(Exception):
+    """A restore's load job came back not ok: leaves the ``restore`` stage
+    unrecorded, for the fail-closed handling that follows."""
+
+    def __init__(self, job):
+        super().__init__(job.failure_reason)
+        self.job = job
 
 
 @dataclass
@@ -274,7 +289,7 @@ class EngineCore:
         )
         self.stage_seconds = self.metrics.histogram(
             "stage_seconds",
-            "Per-stage latency (prefill, prefill_chunk, decode_step, restore)",
+            "Per-stage latency (see EngineCore.stage for the stage names)",
             labels=("stage",),
         )
         self.claim_restores = self.metrics.counter(
@@ -326,17 +341,34 @@ class EngineCore:
         Prometheus exposition reports."""
         return self.fail_closed.as_dict()
 
-    def _observe_stage(self, stage: str, seconds: float) -> None:
-        """One measured stage duration: histogram observation + its ordered
-        witness event, emitted together so the per-stage histogram count
-        always equals the per-stage event count (reconciliation rule).
+    @contextmanager
+    def stage(self, name: str) -> Iterator[None]:
+        """One measured stage: a ``serve.<name>`` profiler span around the
+        body (on the device trace's clock when a trace is running) and, on
+        normal exit, one ``stage_seconds{stage}`` observation with its
+        ordered ``stage_latency`` witness event, emitted together so the
+        per-stage histogram count always equals the per-stage event count
+        (reconciliation rule 5).  The event's ``ts`` is the stage's end, so
+        ``ts - seconds .. ts`` is exactly the span.  A body that raises
+        records nothing.
+
+        Stages: ``prefill``, ``prefill_chunk``, ``decode_step`` (launches,
+        synced on their outputs), ``restore`` (a claim's load job);
+        in the step loop ``step`` (one whole iteration), ``kv_upload`` and
+        ``kv_readback`` (KV copies host->device and device->host, synced),
+        ``page_store`` (host writes of computed KV into pool pages).
+        Inside a ``step`` the other stages never nest in one another.
 
         The event is engine-scoped (``request_id=None``): stage durations
         are wall-clock and batch-wide, so binding them to a request would
         break the byte-identity blast-radius comparisons that project
         per-request (name, payload) streams."""
-        self.stage_seconds.observe(seconds, stage=stage)
-        self.events.emit("stage_latency", stage=stage, seconds=seconds)
+        t0 = time.monotonic()
+        with jax.profiler.TraceAnnotation("serve." + name):
+            yield
+        t1 = time.monotonic()
+        self.stage_seconds.observe(t1 - t0, stage=name)
+        self.events.emit("stage_latency", stage=name, seconds=t1 - t0, ts=t1)
 
     # ------------------------------------------------------------------ claims
     def accept_claim(
@@ -502,14 +534,18 @@ class EngineCore:
                 request_id=req.request_id,
                 predicate=claim.predicate.name,
             )
-        t0 = time.monotonic()
-        job = self.connector.load(
-            hit_blocks,
-            claim_id=restore_claims[0].claim_id if restore_claims else None,
-            request_id=req.request_id,
-            protected_claims=self.scheduler.protected_claim_ids(),
-        )
-        if not job.ok:
+        try:
+            with self.stage("restore"):
+                job = self.connector.load(
+                    hit_blocks,
+                    claim_id=restore_claims[0].claim_id if restore_claims else None,
+                    request_id=req.request_id,
+                    protected_claims=self.scheduler.protected_claim_ids(),
+                )
+                if not job.ok:
+                    raise _LoadFailed(job)  # a failed restore is not timed
+        except _LoadFailed as failed:
+            job = failed.job
             # per-job attribution: the first failing block's (reason,
             # trigger) drives both the refusal reason and the counter label
             reason = job.failure_reason or self.connector.injection.failure_reason
@@ -550,7 +586,6 @@ class EngineCore:
                 "request_finished", request_id=req.request_id, status="FINISHED_ERROR"
             )
             return False
-        self._observe_stage("restore", time.monotonic() - t0)
         for claim in restore_claims:
             self.registry.mark(
                 claim,
@@ -619,10 +654,9 @@ class EngineCore:
                     last_tok[i] = toks[i]
                 else:
                     toks[i] = last_tok[i]
-            t0 = time.monotonic()
-            logits, state = step(state, jnp.asarray(toks), jnp.asarray(pos))
-            jax.block_until_ready(logits)
-            self._observe_stage("decode_step", time.monotonic() - t0)
+            with self.stage("decode_step"):
+                logits, state = step(state, jnp.asarray(toks), jnp.asarray(pos))
+                jax.block_until_ready(logits)
             for i, r in enumerate(reqs):
                 if s + 1 < r.max_new_tokens:
                     pos[i] += 1

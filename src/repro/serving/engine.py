@@ -122,6 +122,10 @@ __all__ = [
 # O(S) collect launch.
 DEFAULT_PREFILL_CHUNK = 32
 
+# step_scheduled payload fields: KV bytes copied host->device (kv_upload)
+# and device->host (kv_readback) during the step's loop iteration
+KV_BYTE_FIELDS = ("upload_bytes", "readback_bytes")
+
 
 @lru_cache(maxsize=16)
 def _jitted_paged_steps(bundle):
@@ -201,6 +205,9 @@ class ServingEngine(EngineCore):
         # one prefill chunk (chunk_len x bucket rows) per step
         self.max_tokens_per_step = max_tokens_per_step
         self._pages_mirror: Optional[Tuple[int, Any, Any]] = None
+        # KV bytes the kv_upload / kv_readback stages copied since the step
+        # loop last took them onto a step_scheduled record
+        self._kv_bytes = dict.fromkeys(KV_BYTE_FIELDS, 0)
         # step-scheduler observability (registered unconditionally so the
         # reconcile rule step_tokens.count == |step_scheduled| holds 0==0
         # for dense/idle engines too)
@@ -208,10 +215,6 @@ class ServingEngine(EngineCore):
             "scheduler_step_tokens",
             "tokens carried per unified scheduler step (decode+feed rows + prefill chunk)",
             buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024),
-        )
-        self.step_occupancy = self.metrics.gauge(
-            "scheduler_step_occupancy",
-            "last step's token load as a fraction of max_tokens_per_step",
         )
         self.decode_stalls = self.metrics.counter(
             "decode_stall_steps_total",
@@ -234,10 +237,6 @@ class ServingEngine(EngineCore):
         self.cow_copies = self.metrics.counter(
             "cow_copies_total",
             "copy-on-write page copies at shared-page divergence points",
-        )
-        self.pages_shared = self.metrics.gauge(
-            "pages_shared",
-            "device pages currently referenced by more than one holder",
         )
         # the pool invokes this once per page_cow emit (metric witness 1:1)
         self.pool.on_cow = self.cow_copies.inc
@@ -325,13 +324,32 @@ class ServingEngine(EngineCore):
                     # frees only: re-key the mirror, bytes are still valid
                     self._pages_mirror = (ver, km, vm)
                 else:
-                    self._pages_mirror = (
-                        ver,
-                        jnp.asarray(pool.k_pages),
-                        jnp.asarray(pool.v_pages),
-                    )
+                    km, vm = self._upload_kv(pool.k_pages, pool.v_pages)
+                    self._pages_mirror = (ver, km, vm)
                 dirty.clear()
         return self._pages_mirror[1], self._pages_mirror[2]
+
+    def _upload_kv(self, *arrays: np.ndarray) -> Tuple[Any, ...]:
+        """Host->device copy of KV as the ``kv_upload`` stage, synced inside
+        it: the next launch consumes the arrays at once, so the wait moves
+        here from that launch.  The bytes go on the next step record."""
+        with self.stage("kv_upload"):
+            out = jax.block_until_ready(tuple(jnp.asarray(a) for a in arrays))
+        self._kv_bytes["upload_bytes"] += sum(a.nbytes for a in arrays)
+        return out
+
+    def _readback_kv(self, *arrays: Any) -> Tuple[np.ndarray, ...]:
+        """Device->host copy of KV as the ``kv_readback`` stage (the copy is
+        synchronous).  The bytes go on the next step record."""
+        with self.stage("kv_readback"):
+            out = tuple(jax.device_get(arrays))
+        self._kv_bytes["readback_bytes"] += sum(a.nbytes for a in out)
+        return out
+
+    def _take_kv_bytes(self) -> Dict[str, int]:
+        """KV bytes copied since the last call (a step record's payload)."""
+        out, self._kv_bytes = self._kv_bytes, dict.fromkeys(KV_BYTE_FIELDS, 0)
+        return out
 
     def _store_prefix_blocks(
         self, req: Request, ck, cv, upto: int, *, start: int = 0, pin: bool = True
@@ -700,13 +718,14 @@ class ServingEngine(EngineCore):
                 tk[:, i, :n] = t["k"]
                 tv[:, i, :n] = t["v"]
                 tpos[i, :n] = t["pos"]
+        k_tail, v_tail = self._upload_kv(tk, tv)
         return {
             "k_pages": jk,
             "v_pages": jv,
             "block_tables": jnp.asarray(bt),
             "prefix_len": jnp.asarray(np.asarray(plens, np.int32)),
-            "k_tail": jnp.asarray(tk),
-            "v_tail": jnp.asarray(tv),
+            "k_tail": k_tail,
+            "v_tail": v_tail,
             "tail_pos": jnp.asarray(tpos),
         }
 
@@ -803,16 +822,15 @@ class ServingEngine(EngineCore):
         for i in range(B):
             r = reqs[i] if i < len(reqs) else reqs[0]
             tokens[i, : len(r.tokens)] = r.tokens
-        t0 = time.monotonic()
-        logits, ck, cv = self._jit_prefill_collect(
-            self.params,
-            {
-                "tokens": jnp.asarray(tokens),
-                "valid_len": jnp.asarray(np.asarray(lens, np.int32)),
-            },
-        )
-        jax.block_until_ready(logits)
-        self._observe_stage("prefill", time.monotonic() - t0)
+        with self.stage("prefill"):
+            logits, ck, cv = self._jit_prefill_collect(
+                self.params,
+                {
+                    "tokens": jnp.asarray(tokens),
+                    "valid_len": jnp.asarray(np.asarray(lens, np.int32)),
+                },
+            )
+            jax.block_until_ready(logits)
         ck = np.asarray(ck)  # [L, B, S, KV, Dh]
         cv = np.asarray(cv)
         stored: List[Tuple[Request, List[KVBlock]]] = []
@@ -910,23 +928,25 @@ class ServingEngine(EngineCore):
         for i in range(B):
             r = reqs[i] if i < len(reqs) else reqs[0]
             tokens[i, : len(r.tokens)] = r.tokens
-        t0 = time.monotonic()
-        logits, ck, cv = self._jit_prefill_collect(
-            self.params,
-            {
-                "tokens": jnp.asarray(tokens),
-                "valid_len": jnp.asarray(np.asarray(lens, np.int32)),
-            },
-        )
-        jax.block_until_ready(logits)
-        self._observe_stage("prefill", time.monotonic() - t0)
-        ck = np.asarray(ck)
-        cv = np.asarray(cv)
+        now = time.monotonic()
+        for req in reqs:
+            req.scheduled_ts = now  # the bucket's queue wait ends here
+        with self.stage("prefill"):
+            logits, ck, cv = self._jit_prefill_collect(
+                self.params,
+                {
+                    "tokens": jnp.asarray(tokens),
+                    "valid_len": jnp.asarray(np.asarray(lens, np.int32)),
+                },
+            )
+            jax.block_until_ready(logits)
+        ck, cv = self._readback_kv(ck, cv)
         stored: List[Tuple[Request, List[KVBlock], int]] = []
         for i, req in enumerate(reqs):
             n = lens[i]
             try:
-                blocks = self._store_prefix_blocks(req, ck[:, i], cv[:, i], n)
+                with self.stage("page_store"):
+                    blocks = self._store_prefix_blocks(req, ck[:, i], cv[:, i], n)
             except PoolExhausted as e:
                 self._refuse_allocation(req, e)
                 continue
@@ -965,12 +985,11 @@ class ServingEngine(EngineCore):
         pin_chain(dev_blocks)
         try:
             if cached == 0:
-                t0 = time.monotonic()
-                logits, cache = self._jit_prefill(
-                    self.params, {"tokens": jnp.asarray([req.tokens], jnp.int32)}
-                )
-                jax.block_until_ready(logits)
-                self._observe_stage("prefill", time.monotonic() - t0)
+                with self.stage("prefill"):
+                    logits, cache = self._jit_prefill(
+                        self.params, {"tokens": jnp.asarray([req.tokens], jnp.int32)}
+                    )
+                    jax.block_until_ready(logits)
                 logits = logits[0]
             else:
                 cache, _n = self._dense_cache(dev_blocks)

@@ -529,12 +529,6 @@ class BlockPool:
             best.last_use = self._clock()
         return best
 
-    def shared_page_count(self) -> int:
-        """Device blocks currently referenced by more than one holder."""
-        return sum(
-            1 for b in self.blocks.values() if b.location == "device" and b.ref > 1
-        )
-
     def assert_consistent(self) -> None:
         """Radix bookkeeping invariants (test/property-suite hook):
         prefix_index maps only to live full chain-matching blocks,

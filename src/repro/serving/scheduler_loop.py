@@ -210,8 +210,8 @@ class PrefillJob:
         This runs INSIDE a mixed step next to live decode rows, so its
         host<->device traffic is batched: one ``jax.device_put`` for all
         four per-chunk operands (instead of four dispatches) and one
-        ``jax.device_get`` for the (k, v) result pair — per-chunk overhead
-        is what decode ITL pays on every contended step."""
+        read-back for the (k, v) result pair — per-chunk overhead is what
+        decode ITL pays on every contended step."""
         eng = self.eng
         bs = eng.block_size
         lo, hi = self.lo, self.lo + self.C
@@ -240,24 +240,30 @@ class PrefillJob:
             "block_tables": d_bt,
             "prefix_len": d_prefix,
         }
-        t0 = time.monotonic()
+        if lo == 0:
+            # the bucket's queue wait ends at its first chunk launch
+            now = time.monotonic()
+            for i in self.alive:
+                self.reqs[i].scheduled_ts = now
         try:
-            ck, cv = eng._jit_prefill_chunk(eng.params, state, d_toks, d_pos)
-            jax.block_until_ready(ck)
+            with eng.stage("prefill_chunk"):
+                ck, cv = eng._jit_prefill_chunk(eng.params, state, d_toks, d_pos)
+                jax.block_until_ready(ck)
         except Exception as e:  # noqa: BLE001 — launch boundary fails closed
             self.abort("prefill_launch_failure", f"{type(e).__name__}: {e}")
             return
-        eng._observe_stage("prefill_chunk", time.monotonic() - t0)
-        ck, cv = jax.device_get((ck, cv))  # [L, B, C, KV, Dh] — the chunk, not O(S)
+        ck, cv = eng._readback_kv(ck, cv)  # [L, B, C, KV, Dh] — the chunk, not O(S)
         for i in list(self.alive):
             req = self.reqs[i]
             upto = min(hi, self.lens[i] - self.lens[i] % bs)
             if upto <= lo:
                 continue
             try:
-                self.chains[i].extend(
-                    eng._store_prefix_blocks(req, ck[:, i], cv[:, i], upto, start=lo)
-                )
+                with eng.stage("page_store"):
+                    chain = eng._store_prefix_blocks(
+                        req, ck[:, i], cv[:, i], upto, start=lo
+                    )
+                self.chains[i].extend(chain)
             except PoolExhausted as e:
                 # fail closed mid-prefill: unwind THIS row's pinned chain;
                 # its already-shared pages stay owned by the bucket mates
@@ -313,6 +319,8 @@ class StepLoop:
         self._members: List[Row] = []  # rows the current state was built for
         self._tail_cap = 0
         self._pad_pos: Optional[int] = None  # frozen pad-row position (decode)
+        # the step records carry the KV bytes this loop copies, no others
+        eng._take_kv_bytes()
 
     # ------------------------------------------------------------- admission
     def _admit(self) -> None:
@@ -442,8 +450,9 @@ class StepLoop:
         else:
             old_k = old_v = old_lg = None
             if self._state is not None:
-                old_k = np.asarray(self._state["k_tail"])
-                old_v = np.asarray(self._state["v_tail"])
+                old_k, old_v = eng._readback_kv(
+                    self._state["k_tail"], self._state["v_tail"]
+                )
                 old_lg = np.asarray(self._logits) if self._logits is not None else None
             tails: List[Optional[Dict[str, Any]]] = []
             for r in rows:
@@ -530,12 +539,16 @@ class StepLoop:
             if self._pad_pos is None:
                 self._pad_pos = rows[0].pos
             poss[len(rows) :] = self._pad_pos
-        t0 = time.monotonic()
+        t_launch = time.monotonic()
+        for row in rows:
+            if row.req.scheduled_ts is None:
+                row.req.scheduled_ts = t_launch
         try:
-            lg, state = eng._jit_paged_decode(
-                eng.params, self._state, jnp.asarray(toks), jnp.asarray(poss)
-            )
-            jax.block_until_ready(lg)
+            with eng.stage("decode_step"):
+                lg, state = eng._jit_paged_decode(
+                    eng.params, self._state, jnp.asarray(toks), jnp.asarray(poss)
+                )
+                jax.block_until_ready(lg)
         except Exception as e:  # noqa: BLE001 — launch boundary fails closed
             reason = f"{type(e).__name__}: {e}"
             for row in rows:
@@ -549,7 +562,6 @@ class StepLoop:
             self._logits = None
             self._members = []
             return (n_dec, n_feed)
-        eng._observe_stage("decode_step", time.monotonic() - t0)
         self._state = state
         self._logits = lg
         for row in rows:
@@ -576,11 +588,12 @@ class StepLoop:
                 # freshly computed KV folds back into pool pages along the
                 # radix path: full blocks are cut from the tail, a matched
                 # partial block grows in place (or COWs if shared)
-                tk = np.asarray(self._state["k_tail"])[:, idx]
-                tv = np.asarray(self._state["v_tail"])[:, idx]
-                eng._fold_sequence_blocks(
-                    req, req.tokens, tk, tv, row.plen, held_blocks=row.blocks
-                )
+                tk, tv = eng._readback_kv(self._state["k_tail"], self._state["v_tail"])
+                with eng.stage("page_store"):
+                    eng._fold_sequence_blocks(
+                        req, req.tokens, tk[:, idx], tv[:, idx], row.plen,
+                        held_blocks=row.blocks,
+                    )
             # the named observation point applies to exact-prefix hits too
             eng._materialize_claims(req, n - n % bs)
         except PoolExhausted as e:
@@ -600,72 +613,83 @@ class StepLoop:
         # the unpin: generated tokens become reusable radix prefix for any
         # later request (best-effort — a full pool skips it).  Only
         # possible while the row's tail still sits in the batched state.
+        eng = self.eng
         if self._state is not None and row in self._members:
             idx = self._members.index(row)
             t = row.pos - row.plen
             if t > 0:
-                tk = np.asarray(self._state["k_tail"])[:, idx, :t]
-                tv = np.asarray(self._state["v_tail"])[:, idx, :t]
-                self.eng._readmit_decode_tail(row.req, row.blocks, row.plen, tk, tv)
+                tk, tv = eng._readback_kv(self._state["k_tail"], self._state["v_tail"])
+                with eng.stage("page_store"):
+                    eng._readmit_decode_tail(
+                        row.req, row.blocks, row.plen, tk[:, idx, :t], tv[:, idx, :t]
+                    )
         unpin_chain(row.blocks)
-        self.eng._finish_ok(row.req)
+        eng._finish_ok(row.req)
         self.rows.remove(row)
 
     # ------------------------------------------------------------------ drive
     def run(self) -> None:
+        while self.waiting or self.pending_fresh or self.rows or self.job:
+            with self.eng.stage("step"):
+                self._step()
+
+    def _step(self) -> None:
+        """One iteration: admit, open a prefill job, launch at most one
+        chunk and one mixed step, and record the step if it launched."""
         eng = self.eng
         budget = eng.max_tokens_per_step
-        while self.waiting or self.pending_fresh or self.rows or self.job:
-            self._admit()
-            self._start_job()
-            # ONE mirror snapshot per step, taken before the chunk launch
-            # stores anything: admissions/restores above are covered, and
-            # the decode side never re-uploads for pages its pinned block
-            # tables cannot reference (see _sync_state)
-            pages = eng._device_pages()
-            prefill_tokens = 0
-            prefill_rows = 0
-            # chunk side: at most one in-flight prefill chunk per step, only
-            # when it fits the budget next to the live rows — unless there
-            # are no live rows (livelock guard: an oversized chunk still
-            # runs as the only work of the step)
-            if self.job is not None:
-                cost = self.job.chunk_tokens
-                if not self.rows or len(self.rows) + cost <= budget:
-                    prefill_rows = len(self.job.alive)
-                    self.job.advance()
-                    prefill_tokens = cost
-                    if self.job.done:
-                        self.rows.extend(self.job.take_rows())
-                        self.job = None
-                        # the joined rows feed THIS step and their block
-                        # tables reference the job's freshly stored pages —
-                        # refresh the snapshot (one upload per bucket)
-                        pages = eng._device_pages()
-            # decode side: every live row launches every step — the budget
-            # never holds a decode row back (zero decode stalls)
-            stalled = bool(self.rows)
-            n_dec, n_feed = self._mixed_step(pages) if self.rows else (0, 0)
-            launched_mixed = (n_dec + n_feed) > 0
-            if stalled and not launched_mixed and prefill_tokens == 0:
-                # structurally unreachable; counted (and gated to 0 in
-                # bench_scheduler) rather than assumed
-                eng.decode_stalls.inc()
-            if launched_mixed or prefill_tokens:
-                step_tokens = n_dec + n_feed + prefill_tokens
-                eng.step_tokens.observe(step_tokens)
-                eng.step_occupancy.set(step_tokens / budget)
-                eng.events.emit(
-                    "step_scheduled",
-                    step=self.step_idx,
-                    n_rows=n_dec + n_feed,
-                    n_decode=n_dec,
-                    n_feed=n_feed,
-                    prefill_rows=prefill_rows,
-                    prefill_tokens=prefill_tokens,
-                    step_tokens=step_tokens,
-                    budget=budget,
-                )
-                self.step_idx += 1
-            # point-in-time sharing gauge (reconcile-exempt by nature)
-            eng.pages_shared.set(eng.pool.shared_page_count())
+        self._admit()
+        self._start_job()
+        # ONE mirror snapshot per step, taken before the chunk launch
+        # stores anything: admissions/restores above are covered, and
+        # the decode side never re-uploads for pages its pinned block
+        # tables cannot reference (see _sync_state)
+        pages = eng._device_pages()
+        prefill_tokens = 0
+        prefill_rows = 0
+        # chunk side: at most one in-flight prefill chunk per step, only
+        # when it fits the budget next to the live rows — unless there
+        # are no live rows (livelock guard: an oversized chunk still
+        # runs as the only work of the step)
+        if self.job is not None:
+            cost = self.job.chunk_tokens
+            if not self.rows or len(self.rows) + cost <= budget:
+                prefill_rows = len(self.job.alive)
+                self.job.advance()
+                prefill_tokens = cost
+                if self.job.done:
+                    self.rows.extend(self.job.take_rows())
+                    self.job = None
+                    # the joined rows feed THIS step and their block
+                    # tables reference the job's freshly stored pages —
+                    # refresh the snapshot (one upload per bucket)
+                    pages = eng._device_pages()
+        # decode side: every live row launches every step — the budget
+        # never holds a decode row back (zero decode stalls)
+        stalled = bool(self.rows)
+        n_dec, n_feed = self._mixed_step(pages) if self.rows else (0, 0)
+        launched_mixed = (n_dec + n_feed) > 0
+        if stalled and not launched_mixed and prefill_tokens == 0:
+            # structurally unreachable; counted (and gated to 0 in
+            # bench_scheduler) rather than assumed
+            eng.decode_stalls.inc()
+        if launched_mixed or prefill_tokens:
+            step_tokens = n_dec + n_feed + prefill_tokens
+            eng.step_tokens.observe(step_tokens)
+            # KV bytes of an iteration that launched nothing ride on the
+            # next record, so the records' sums are complete
+            moved = eng._take_kv_bytes()
+            eng.events.emit(
+                "step_scheduled",
+                step=self.step_idx,
+                n_rows=n_dec + n_feed,
+                n_decode=n_dec,
+                n_feed=n_feed,
+                prefill_rows=prefill_rows,
+                prefill_tokens=prefill_tokens,
+                step_tokens=step_tokens,
+                budget=budget,
+                upload_bytes=moved["upload_bytes"],
+                readback_bytes=moved["readback_bytes"],
+            )
+            self.step_idx += 1

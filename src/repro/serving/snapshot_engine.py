@@ -21,7 +21,6 @@ matches the never-offloaded run (tests/test_snapshot_claims.py).
 """
 from __future__ import annotations
 
-import time
 from functools import lru_cache
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -127,12 +126,11 @@ class SnapshotEngine(EngineCore):
         claim = self.registry.get(claim_id)
         prefix = self._claim_prefixes[claim_id]
         req = self._new_request(prefix, 0)
-        t0 = time.monotonic()
-        logits, state = self._jit_prefill(
-            self.params, {"tokens": jnp.asarray([prefix], jnp.int32)}
-        )
-        jax.block_until_ready(logits)
-        self._observe_stage("prefill", time.monotonic() - t0)
+        with self.stage("prefill"):
+            logits, state = self._jit_prefill(
+                self.params, {"tokens": jnp.asarray([prefix], jnp.int32)}
+            )
+            jax.block_until_ready(logits)
         # snapshot = (state, next-token logits): a recurrent state update is
         # NOT idempotent, so exact-prefix reuse must consume the stored
         # logits rather than replaying the last token through the state.
@@ -191,12 +189,11 @@ class SnapshotEngine(EngineCore):
 
         # prefill any uncached part / decode from the (restored) state
         if state is None:
-            t0 = time.monotonic()
-            logits, state = self._jit_prefill(
-                self.params, {"tokens": jnp.asarray([toks], jnp.int32)}
-            )
-            jax.block_until_ready(logits)
-            self._observe_stage("prefill", time.monotonic() - t0)
+            with self.stage("prefill"):
+                logits, state = self._jit_prefill(
+                    self.params, {"tokens": jnp.asarray([toks], jnp.int32)}
+                )
+                jax.block_until_ready(logits)
             logits = logits[0]
         else:
             for i, tok in enumerate(toks[consumed:]):

@@ -31,8 +31,15 @@ Span vocabulary (``Span.name`` / ``cat``):
                 -> the request's terminal event; ``args.trigger`` carries
                 the fail-closed attribution
   stage:<s>     a ``stage_latency`` event unfolded backward by its
-                measured ``seconds`` (engine-scoped slices: prefill,
-                prefill_chunk, decode_step, restore)
+                measured ``seconds`` (engine-scoped slices, from
+                ``EngineCore.stage``): the launches ``prefill``,
+                ``prefill_chunk``, ``decode_step``; ``restore``; and in the
+                step loop ``step`` (one iteration) around the disjoint
+                ``kv_upload``, ``kv_readback`` and ``page_store``
+
+The same stages run inside ``serve.<s>`` ``jax.profiler`` annotations, so
+a profiler trace holds them on the device's clock; this module replays the
+event log, which has no device clock.
 
 Instants: ``tier_quarantined`` and ``transfer_retry_scheduled`` render as
 Perfetto instant events on their track; ``batch_scheduled`` and the unified
@@ -331,6 +338,8 @@ def build_instants(log: EventLog) -> List[Instant]:
                         "n_feed": e.payload.get("n_feed"),
                         "prefill_tokens": e.payload.get("prefill_tokens"),
                         "budget": e.payload.get("budget"),
+                        "upload_bytes": e.payload.get("upload_bytes"),
+                        "readback_bytes": e.payload.get("readback_bytes"),
                     },
                 )
             )
