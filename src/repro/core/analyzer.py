@@ -561,6 +561,11 @@ def check_metrics_reconcile(log: EventLog, metrics) -> Verdict:
          events (one per admission that found resident prefix pages).
       8. ``cow_copies_total`` equals the count of ``page_cow`` events (one
          per copy-on-write at a shared-page divergence point).
+      9. ``page_mirror_full_uploads_total`` and
+         ``page_mirror_scatter_syncs_total`` equal the counts of
+         ``page_mirror_synced`` events by ``path``, and
+         ``page_mirror_pages_scattered_total`` the sum of the scatter
+         events' ``pages``.
 
     ``metrics`` may be a live ``serving.metrics.MetricsRegistry`` or its
     ``snapshot()`` dict (the serialized form the CI artifacts carry).
@@ -671,6 +676,22 @@ def check_metrics_reconcile(log: EventLog, metrics) -> Verdict:
             f"cow_copies_total {n_cow_m} != page_cow count {n_cow_ev}"
         )
     reasons.append(f"cow_copies_total == page_cow events ({n_cow_ev})")
+
+    # rule 9: page_mirror_* counters <-> page_mirror_synced events by path
+    syncs = log.named("page_mirror_synced")
+    scattered = [e.payload["pages"] for e in syncs if e.payload["path"] == "scatter"]
+    witnessed = (len(syncs) - len(scattered), len(scattered), sum(scattered))
+    counted = (
+        sum(_counter_series(snap, "page_mirror_full_uploads_total").values()),
+        sum(_counter_series(snap, "page_mirror_scatter_syncs_total").values()),
+        sum(_counter_series(snap, "page_mirror_pages_scattered_total").values()),
+    )
+    if counted != witnessed:
+        return Verdict.fail(
+            "page_mirror (full uploads, scatter syncs, pages scattered) "
+            f"{counted} != page_mirror_synced events {witnessed}"
+        )
+    reasons.append(f"page_mirror counters == page_mirror_synced events ({len(syncs)})")
 
     return Verdict(True, reasons)
 

@@ -112,6 +112,12 @@ NATIVE_EVENTS = (
     "prefix_reuse",
     "page_extend",
     "page_cow",
+    # device page mirror (serving/engine.py _device_pages): one sync that
+    # changed the mirror's bytes, engine-scoped — path "full" (the whole
+    # pool uploaded) or "scatter" (only the dirty pages copied and
+    # scattered; ``pages`` counts them, padding excluded) — the ordered
+    # witness for the page_mirror_* counters
+    "page_mirror_synced",
 )
 
 ALL_EVENT_NAMES = frozenset(E.values()) | frozenset(NATIVE_EVENTS)
@@ -199,6 +205,7 @@ PAYLOAD_SCHEMA: Dict[str, frozenset] = {
     "page_cow": frozenset(
         {"block_id", "new_block_id", "page_index", "new_page_index", "refcount"}
     ),
+    "page_mirror_synced": frozenset({"path", "pages"}),
 }
 
 PAYLOAD_OPTIONAL: Dict[str, frozenset] = {

@@ -67,7 +67,7 @@ from __future__ import annotations
 
 import math
 import time
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
@@ -140,6 +140,13 @@ def _jitted_paged_steps(bundle):
     )
 
 
+@jax.jit
+def _scatter_pages(km, vm, idx, kd, vd):
+    """Page slots ``idx`` of the K/V mirror ([L, KV, N, page, Dh]) set to
+    ``kd``/``vd`` ([L, KV, len(idx), page, Dh]), out of place."""
+    return km.at[:, :, idx].set(kd), vm.at[:, :, idx].set(vd)
+
+
 class ServingEngine(EngineCore):
     """Claim-native engine over KV block chains: paged decode + batching."""
 
@@ -205,6 +212,20 @@ class ServingEngine(EngineCore):
         # one prefill chunk (chunk_len x bucket rows) per step
         self.max_tokens_per_step = max_tokens_per_step
         self._pages_mirror: Optional[Tuple[int, Any, Any]] = None
+        # host buffers of the dirty pages a mirror sync uploads, per count
+        self._page_bufs: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+        self.mirror_full_uploads = self.metrics.counter(
+            "page_mirror_full_uploads_total",
+            "uploads of the whole page pool to its device mirror",
+        )
+        self.mirror_scatter_syncs = self.metrics.counter(
+            "page_mirror_scatter_syncs_total",
+            "device mirror syncs that scattered only the dirty page slots",
+        )
+        self.mirror_pages_scattered = self.metrics.counter(
+            "page_mirror_pages_scattered_total",
+            "dirty pages copied by scatter syncs (padding not counted)",
+        )
         # KV bytes the kv_upload / kv_readback stages copied since the step
         # loop last took them onto a step_scheduled record
         self._kv_bytes = dict.fromkeys(KV_BYTE_FIELDS, 0)
@@ -294,47 +315,90 @@ class ServingEngine(EngineCore):
         return cache, n
 
     def _device_pages(self) -> Tuple[Any, Any]:
-        """jnp mirror of the pool page store, rebuilt only when pages change
-        (version-keyed).  Page frees alone never re-upload: no block table
-        references a freed slot, so stale mirror bytes there are
-        unreachable and the existing device arrays are simply re-keyed
-        (mid-stream completions between steps would otherwise force a full
-        upload onto the next step's critical path).  A scatter-update of
-        just the dirty slots is NOT profitable here: without buffer
-        donation ``.at[].set`` copies the whole mirror, and donation is
-        unsound because live decode states alias these arrays.  On the TPU
-        too the page store is this host mirror, re-uploaded whole after any
-        page write — the pool is not yet device-resident."""
+        """Device mirror of the pool page store, keyed by the pool's page
+        version and brought up to date by the cheapest of three paths:
+
+        * frees only (no dirty page): the mirror is re-keyed.  No block
+          table references a freed slot, so its stale bytes are unreachable.
+        * a mirror of the pool's shape exists: the dirty page slots alone
+          are copied to the device and scattered into the mirror, out of
+          place, inside one ``kv_upload`` stage.  Their count is padded to
+          a power of two by repeating the last slot with its own bytes
+          (the duplicate writes agree), so there is one scatter program
+          per power of two below the capacity.  On the chip the scatter
+          costs one HBM read and write of the mirror (1.65 ms for each of
+          K and V of a 1.05-GiB pool on a TPU v5e) against the ~150 ms of
+          the whole pool's host-to-device copy it replaces.
+        * the first build, a shape change, or a padded dirty count that
+          would reach the capacity: the whole pool is uploaded.
+
+        The scatter does not donate the old mirror: live decode states and
+        the step loop's page snapshot still hold it, and keep valid arrays.
+        The pool itself is not yet device-resident: this mirror is its
+        only device copy."""
         pool = self.pool
         ver = pool._pages_version if pool.k_pages is not None else -1
-        if self._pages_mirror is None or self._pages_mirror[0] != ver:
-            if pool.k_pages is None:
-                cfg = self.cfg
-                z = jnp.zeros(
-                    (cfg.num_layers, cfg.num_kv_heads, 1, self.block_size, cfg.resolved_head_dim),
-                    jnp.bfloat16,
-                )
-                self._pages_mirror = (ver, z, z)
-            else:
-                dirty = pool._dirty_pages
-                km = vm = None
-                if self._pages_mirror is not None:
-                    _, km, vm = self._pages_mirror
-                if km is not None and km.shape == pool.k_pages.shape and not dirty:
-                    # frees only: re-key the mirror, bytes are still valid
-                    self._pages_mirror = (ver, km, vm)
-                else:
-                    km, vm = self._upload_kv(pool.k_pages, pool.v_pages)
-                    self._pages_mirror = (ver, km, vm)
-                dirty.clear()
-        return self._pages_mirror[1], self._pages_mirror[2]
+        if self._pages_mirror is not None and self._pages_mirror[0] == ver:
+            return self._pages_mirror[1], self._pages_mirror[2]
+        if pool.k_pages is None:
+            cfg = self.cfg
+            z = jnp.zeros(
+                (cfg.num_layers, cfg.num_kv_heads, 1, self.block_size, cfg.resolved_head_dim),
+                jnp.bfloat16,
+            )
+            self._pages_mirror = (ver, z, z)
+            return z, z
+        dirty = pool._dirty_pages
+        km = vm = None
+        if self._pages_mirror is not None:
+            _, km, vm = self._pages_mirror
+        n = len(dirty)
+        pad = 1 << (n - 1).bit_length() if n else 0
+        if km is None or km.shape != pool.k_pages.shape or pad >= pool.k_pages.shape[2]:
+            km, vm = self._upload_kv(pool.k_pages, pool.v_pages)
+            self.mirror_full_uploads.inc()
+            self.events.emit("page_mirror_synced", path="full", pages=pool.k_pages.shape[2])
+        elif n:
+            idx = np.fromiter(sorted(dirty), np.int32, n)
+            idx = np.pad(idx, (0, pad - n), mode="edge")
+            km, vm = self._upload_kv(
+                *self._gather_pages(idx), then=partial(_scatter_pages, km, vm, idx)
+            )
+            self.mirror_scatter_syncs.inc()
+            self.mirror_pages_scattered.inc(n)
+            self.events.emit("page_mirror_synced", path="scatter", pages=n)
+        dirty.clear()
+        self._pages_mirror = (ver, km, vm)
+        return km, vm
 
-    def _upload_kv(self, *arrays: np.ndarray) -> Tuple[Any, ...]:
+    def _gather_pages(self, idx: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        """The pool's K and V page slots ``idx`` ([L, KV, len(idx), page,
+        Dh]) copied into host buffers kept per count: fresh host memory
+        costs a page fault per 4 KiB on first touch, which made a 256-page
+        gather into new arrays take 266 ms on the TPU v5e host, against
+        33 ms for the upload of the result.  Each buffer is free again
+        once ``_upload_kv`` has synced on the scatter that reads it."""
+        pool = self.pool
+        bufs = self._page_bufs.get(len(idx))
+        if bufs is None:
+            shape = pool.k_pages.shape[:2] + (len(idx),) + pool.k_pages.shape[3:]
+            bufs = (np.empty(shape, pool.k_pages.dtype), np.empty(shape, pool.k_pages.dtype))
+            self._page_bufs[len(idx)] = bufs
+        for pages, buf in zip((pool.k_pages, pool.v_pages), bufs):
+            np.take(pages, idx, axis=2, out=buf, mode="clip")  # "raise" buffers its copy
+        return bufs
+
+    def _upload_kv(self, *arrays: np.ndarray, then=None) -> Tuple[Any, ...]:
         """Host->device copy of KV as the ``kv_upload`` stage, synced inside
         it: the next launch consumes the arrays at once, so the wait moves
-        here from that launch.  The bytes go on the next step record."""
+        here from that launch.  ``then``, if given, runs on the copies
+        inside the same stage and its result is synced and returned in
+        their place.  The bytes copied go on the next step record."""
         with self.stage("kv_upload"):
-            out = jax.block_until_ready(tuple(jnp.asarray(a) for a in arrays))
+            out = tuple(jnp.asarray(a) for a in arrays)
+            if then is not None:
+                out = then(*out)
+            out = jax.block_until_ready(out)
         self._kv_bytes["upload_bytes"] += sum(a.nbytes for a in arrays)
         return out
 

@@ -164,10 +164,10 @@ class BlockPool:
         self.v_pages: Optional[np.ndarray] = None
         self._free_pages: List[int] = []
         self._pages_version = 0  # bumped on any page write (jnp mirror key)
-        # page slots written since the jnp mirror last synced: lets the
-        # engine scatter-update just these slots instead of re-uploading
-        # the whole pool on every chunked-prefill store (the mirror
-        # consumer drains this set when it syncs)
+        # page slots written since the device mirror last synced: the
+        # engine copies and scatters just these slots into the mirror
+        # instead of re-uploading the whole pool after every page write,
+        # and clears the set when it syncs (ServingEngine._device_pages)
         self._dirty_pages: set = set()
 
     # -- page store -----------------------------------------------------------
