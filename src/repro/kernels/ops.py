@@ -43,13 +43,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths, *, softcap=0.0, 
 @partial(jax.jit, static_argnames=("softcap", "window", "interpret"))
 def paged_decode_attention(
     q, k_pages, v_pages, block_tables, prefix_len, k_tail, v_tail, tail_pos,
-    cur_pos, *, softcap=0.0, window=0, interpret=None,
+    cur_pos, *, softcap=0.0, window=0, layer=None, interpret=None,
 ):
-    """Batched serving decode: block-table prefix + in-flight tail."""
+    """Batched serving decode: block-table prefix + in-flight tail (``layer``
+    picks one layer of a stacked [L, KV, N, page, D] pool)."""
     interpret = _interpret_default() if interpret is None else interpret
     return paged_decode_attention_pallas(
         q, k_pages, v_pages, block_tables, prefix_len, k_tail, v_tail,
-        tail_pos, cur_pos, softcap=softcap, window=window, interpret=interpret,
+        tail_pos, cur_pos, softcap=softcap, window=window, layer=layer,
+        interpret=interpret,
     )
 
 

@@ -344,6 +344,7 @@ def paged_attention_decode(
     *,
     window: int = 0,
     softcap: float = 0.0,
+    layer=None,
 ):
     """Single-step decode attention over PAGED prefix KV plus a dense tail.
 
@@ -353,7 +354,9 @@ def paged_attention_decode(
     tokens) that are not yet page-resident.
 
     q:            [B, 1, H, D]
-    k/v_pages:    [KV, N, page, D]   (this layer's slice of the pool)
+    k/v_pages:    [KV, N, page, D]   (this layer's slice of the pool), or
+                  [L, KV, N, page, D] (the stacked pool) with ``layer`` the
+                  index of this layer
     block_tables: [B, P] int32       page ids per request (padding masked
                                      by prefix_len)
     prefix_len:   [B] int32          tokens addressed via the block table
@@ -363,19 +366,22 @@ def paged_attention_decode(
     Returns [B, 1, H, D].
 
     On the TPU target this lowers to the Pallas paged-attention decode
-    kernel (kernels/paged_attention.paged_decode_attention_pallas), which
-    streams pages HBM->VMEM via the scalar-prefetched block table; this jnp
-    formulation is the same math expressed with an explicit page gather.
+    kernel (kernels/paged_attention.paged_decode_attention_pallas): each of
+    its grid steps DMAs a run of a row's block-table pages, all KV heads
+    of a page per copy, from the pool in HBM into VMEM, the next run's
+    copies in flight while this one is attended.  This jnp formulation is
+    the same math expressed with an explicit page gather.
     """
     B = q.shape[0]
-    page = k_pages.shape[2]
+    page = k_pages.shape[-2]
     P = block_tables.shape[1]
     if jax.default_backend() == "tpu":
-        # stream pages HBM->VMEM through the scalar-prefetched block table —
-        # the pool is read strictly in place, nothing is gathered densely
+        # copy pages HBM->VMEM by the block table inside the kernel — the
+        # pool is read strictly in place, nothing is gathered densely
         from repro.kernels.ops import paged_decode_attention
 
-        KV, G = k_pages.shape[0], q.shape[2] // k_pages.shape[0]
+        KV = k_pages.shape[-4]
+        G = q.shape[2] // KV
         out = paged_decode_attention(
             q[:, 0].reshape(B, KV, G, q.shape[3]),
             k_pages,
@@ -388,8 +394,11 @@ def paged_attention_decode(
             cur_pos,
             softcap=softcap,
             window=window,
+            layer=layer,
         )
         return out.reshape(B, 1, q.shape[2], q.shape[3])
+    if layer is not None:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     # gather the referenced pages: [KV, B, P, page, D] -> [B, P*page, KV, D]
     kd = jnp.transpose(k_pages[:, block_tables], (1, 2, 3, 0, 4)).reshape(
         B, P * page, k_pages.shape[0], k_pages.shape[3]
@@ -522,14 +531,15 @@ def attn_paged_prefill_layer(
 
 def attn_paged_decode_layer(
     p, cfg, x, k_pages, v_pages, block_tables, prefix_len,
-    tail_k, tail_v, tail_pos, cur_pos, tail_slot, *, use_rope=True
+    tail_k, tail_v, tail_pos, cur_pos, tail_slot, *, layer=None, use_rope=True
 ):
     """One-token decode over paged prefix KV: writes the new (k, v) into the
     tail at ``tail_slot`` and attends pages + tail in place.
 
-    x: [B, 1, d]; k/v_pages: [KV, N, page, Dh]; tail_k/v: [B, T, KV, Dh];
-    tail_pos: [B, T] (already updated with cur_pos at tail_slot).
-    Returns (out [B, 1, d], new_tail_k, new_tail_v).
+    x: [B, 1, d]; k/v_pages: [KV, N, page, Dh], or the stacked
+    [L, KV, N, page, Dh] pool with ``layer`` its index; tail_k/v:
+    [B, T, KV, Dh]; tail_pos: [B, T] (already updated with cur_pos at
+    tail_slot).  Returns (out [B, 1, d], new_tail_k, new_tail_v).
     """
     B = x.shape[0]
     q, k, v = attn_qkv(p, cfg, x, cur_pos[:, None], use_rope=use_rope)
@@ -540,7 +550,7 @@ def attn_paged_decode_layer(
     out = paged_attention_decode(
         q, k_pages, v_pages, block_tables, prefix_len,
         new_tk, new_tv, tail_pos, cur_pos,
-        window=cfg.sliding_window, softcap=cfg.attn_logit_softcap,
+        window=cfg.sliding_window, softcap=cfg.attn_logit_softcap, layer=layer,
     )
     out = out.reshape(B, 1, -1) @ p["wo"]
     return out, new_tk, new_tv

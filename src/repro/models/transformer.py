@@ -408,14 +408,14 @@ def paged_decode_step(params, cfg, state, tokens, cur_pos, *, mesh=None, moe_str
 
     def body(carry, xs):
         x, = carry
-        lp, kp, vp, tk, tv = xs
+        lp, layer, tk, tv = xs
         x = constrain_activations(x, mesh, seq_dim=None)
         with jax.named_scope("attention"):
             h = apply_norm(cfg.norm, lp["ln1"], x)
             a, ntk, ntv = attn_paged_decode_layer(
-                lp["attn"], cfg, h, kp, vp,
+                lp["attn"], cfg, h, state["k_pages"], state["v_pages"],
                 state["block_tables"], state["prefix_len"],
-                tk, tv, tail_pos, cur_pos, slot,
+                tk, tv, tail_pos, cur_pos, slot, layer=layer,
             )
             x = x + a
         with jax.named_scope("mlp"):
@@ -427,10 +427,11 @@ def paged_decode_step(params, cfg, state, tokens, cur_pos, *, mesh=None, moe_str
             x = x + m
         return (x,), (constrain_activations(ntk, mesh), constrain_activations(ntv, mesh))
 
+    # the pool stays stacked: each layer reads its pages in place by index
     (x,), (ntk, ntv) = jax.lax.scan(
         body,
         (x,),
-        (params["layers"], state["k_pages"], state["v_pages"], state["k_tail"], state["v_tail"]),
+        (params["layers"], jnp.arange(cfg.num_layers), state["k_tail"], state["v_tail"]),
     )
     with jax.named_scope("head"):
         x = apply_norm(cfg.norm, params["final_norm"], x)

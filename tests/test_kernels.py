@@ -128,26 +128,49 @@ def test_paged_attention_matches_dense_decode():
 
 
 # ------------------------------------------------- paged decode (serving entry)
+def _decode_case(*values, id=None):
+    """One row of the paged-decode sweep; without ``id`` it is named as
+    pytest names a plain row."""
+    return pytest.param(*values, id=id or "-".join(str(v) for v in values[:9]))
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "B,KV,G,D,page,P,N,T,window",
+    "B,KV,G,D,page,P,N,T,window,softcap,prefix",
     [
-        (2, 2, 2, 16, 4, 4, 16, 8, 0),
-        (1, 4, 1, 32, 8, 3, 8, 4, 0),
-        (3, 1, 4, 16, 4, 5, 32, 8, 12),  # sliding window
+        _decode_case(2, 2, 2, 16, 4, 4, 16, 8, 0, 0.0, None),
+        _decode_case(1, 4, 1, 32, 8, 3, 8, 4, 0, 0.0, None),
+        _decode_case(3, 1, 4, 16, 4, 5, 32, 8, 12, 0.0, None),  # sliding window
+        # MHA as deepseek-7b has it (KV 32, G 1), at a small head dim
+        _decode_case(2, 32, 1, 16, 8, 5, 24, 8, 0, 0.0, None, id="mha_kv32_g1"),
+        _decode_case(2, 2, 4, 32, 4, 6, 16, 8, 0, 0.0, None, id="gqa_g4"),
+        # qwen3 widths: 8 pages a step in bf16, 4 in f32, so P = 13 leaves a
+        # short last block; rows end on a page, mid-page, and hold no page
+        _decode_case(3, 8, 2, 128, 8, 13, 40, 16, 0, 0.0, (104, 77, 0), id="qwen3_widths_p13"),
+        # deepseek widths: 2 pages a step in bf16, 1 in f32
+        _decode_case(2, 32, 1, 128, 8, 5, 12, 8, 0, 0.0, (40, 21), id="mha_widths_p5"),
+        _decode_case(4, 2, 2, 16, 4, 6, 16, 8, 0, 0.0, (0, 7, 24, 1), id="empty_and_midpage_rows"),
+        _decode_case(3, 2, 2, 16, 4, 6, 16, 8, 10, 30.0, (20, 0, 13), id="window_softcap"),
     ],
 )
-def test_paged_decode_attention_matches_ref(dtype, B, KV, G, D, page, P, N, T, window):
+def test_paged_decode_attention_matches_ref(dtype, B, KV, G, D, page, P, N, T, window, softcap, prefix):
     """The batched serving entry point (block-table prefix + in-flight tail)
-    against the dense-gather oracle."""
+    against the dense-gather oracle.  Where ``prefix`` gives the rows'
+    prefix lengths, the table's padding past them points at page 0."""
     rng = np.random.default_rng(5)
     q = _rand(rng, (B, KV, G, D), dtype)
     k_pages = _rand(rng, (KV, N, page, D), dtype)
     v_pages = _rand(rng, (KV, N, page, D), dtype)
     k_tail = _rand(rng, (B, KV, T, D), dtype)
     v_tail = _rand(rng, (B, KV, T, D), dtype)
-    block_tables = jnp.asarray(rng.integers(0, N, (B, P)), jnp.int32)
-    prefix_len = jnp.asarray(rng.integers(1, P * page + 1, (B,)), jnp.int32)
+    block_tables = np.asarray(rng.integers(0, N, (B, P)), np.int32)
+    if prefix is None:
+        prefix_len = jnp.asarray(rng.integers(1, P * page + 1, (B,)), jnp.int32)
+    else:
+        prefix_len = jnp.asarray(prefix, jnp.int32)
+        for b, n in enumerate(prefix):
+            block_tables[b, -(-n // page):] = 0
+    block_tables = jnp.asarray(block_tables)
     t_used = rng.integers(1, T + 1, (B,))
     tail_pos = np.full((B, T), -1, np.int32)
     for b in range(B):
@@ -155,11 +178,48 @@ def test_paged_decode_attention_matches_ref(dtype, B, KV, G, D, page, P, N, T, w
     cur_pos = jnp.asarray(np.asarray(prefix_len) + t_used - 1, jnp.int32)
     args = (q, k_pages, v_pages, block_tables, prefix_len, k_tail, v_tail,
             jnp.asarray(tail_pos), cur_pos)
-    out = ops.paged_decode_attention(*args, window=window)
-    expect = ref.paged_decode_attention_ref(*args, window=window)
+    out = ops.paged_decode_attention(*args, window=window, softcap=softcap)
+    expect = ref.paged_decode_attention_ref(*args, window=window, softcap=softcap)
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(expect, np.float32), **TOLS[dtype]
     )
+
+
+@pytest.mark.parametrize(
+    "KV,page,D,itemsize,P,ppb",
+    [
+        (8, 8, 128, 2, 256, 8),  # qwen3-1.7b: 16 KiB a page
+        (32, 8, 128, 2, 256, 2),  # deepseek-7b (MHA): 64 KiB a page
+        (8, 8, 128, 4, 256, 4),  # a float32 pool halves the pages
+        (8, 8, 128, 2, 5, 5),  # capped by the table width
+        (2, 4, 16, 2, 0, 1),  # an empty table
+    ],
+)
+def test_pages_per_block_from_shapes(KV, page, D, itemsize, P, ppb):
+    from repro.kernels.paged_attention import pages_per_block
+
+    assert pages_per_block(KV, page, D, itemsize, P) == ppb
+
+
+def test_paged_decode_attention_reads_stacked_pool_by_layer():
+    """A stacked [L, KV, N, page, D] pool read with ``layer`` gives what
+    that layer's slice gives."""
+    rng = np.random.default_rng(8)
+    L, B, KV, G, D, page, P, N, T = 3, 2, 2, 2, 16, 4, 4, 8, 8
+    k_pages = _rand(rng, (L, KV, N, page, D), jnp.float32)
+    v_pages = _rand(rng, (L, KV, N, page, D), jnp.float32)
+    tail_pos = np.full((B, T), -1, np.int32)
+    tail_pos[:, :2] = np.asarray([[13, 14], [9, 10]])
+    args = (
+        jnp.asarray(rng.integers(0, N, (B, P)), jnp.int32), jnp.asarray([13, 9], jnp.int32),
+        _rand(rng, (B, KV, T, D), jnp.float32), _rand(rng, (B, KV, T, D), jnp.float32),
+        jnp.asarray(tail_pos), jnp.asarray([14, 10], jnp.int32),
+    )
+    q = _rand(rng, (B, KV, G, D), jnp.float32)
+    for layer in range(L):
+        stacked = ops.paged_decode_attention(q, k_pages, v_pages, *args, layer=jnp.int32(layer))
+        sliced = ops.paged_decode_attention(q, k_pages[layer], v_pages[layer], *args)
+        np.testing.assert_array_equal(np.asarray(stacked), np.asarray(sliced))
 
 
 def test_paged_decode_attention_matches_model_helper():

@@ -58,11 +58,11 @@ def _spec(sharding):
     )
 
 
-def _decode_kernel(s, B):
+def _decode_kernel(s, B, kv=KV, g=G, p=P):
     args = (
-        s((B, KV, G, D)), s((KV, N_PAGES, PAGE, D)), s((KV, N_PAGES, PAGE, D)),
-        s((B, P), jnp.int32), s((B,), jnp.int32),
-        s((B, KV, T, D)), s((B, KV, T, D)),
+        s((B, kv, g, D)), s((kv, N_PAGES, PAGE, D)), s((kv, N_PAGES, PAGE, D)),
+        s((B, p), jnp.int32), s((B,), jnp.int32),
+        s((B, kv, T, D)), s((B, kv, T, D)),
         s((B, T), jnp.int32), s((B,), jnp.int32),
     )
     return paged_decode_attention_pallas, args
@@ -105,6 +105,10 @@ def _decode_step(s):
 CASES = {
     "paged_decode_b1": lambda s: _decode_kernel(s, 1),
     "paged_decode_b4": lambda s: _decode_kernel(s, 4),  # (1, T) tail block regression
+    # a run of 8 pages with all 8 KV heads a grid step (qwen3-1.7b)
+    "paged_decode_b8_p256": lambda s: _decode_kernel(s, 8, p=256),
+    # deepseek-7b's MHA: KV 32, G 1, 2 pages a step
+    "paged_decode_mha_kv32_b8_p256": lambda s: _decode_kernel(s, 8, kv=32, g=1, p=256),
     "chunked_prefill_b4": lambda s: _prefill_kernel(s, 4),
     "kv_block_copy": _block_copy,
     "paged_decode_step": _decode_step,
