@@ -402,10 +402,14 @@ class ServingEngine(EngineCore):
         self._kv_bytes["upload_bytes"] += sum(a.nbytes for a in arrays)
         return out
 
-    def _readback_kv(self, *arrays: Any) -> Tuple[np.ndarray, ...]:
+    def _readback_kv(self, *arrays: Any, take=None) -> Tuple[np.ndarray, ...]:
         """Device->host copy of KV as the ``kv_readback`` stage (the copy is
-        synchronous).  The bytes go on the next step record."""
+        synchronous).  ``take``, if given, runs on the arrays inside the same
+        stage and only the arrays it returns are copied.  The bytes copied go
+        on the next step record."""
         with self.stage("kv_readback"):
+            if take is not None:
+                arrays = tuple(take(*arrays))
             out = tuple(jax.device_get(arrays))
         self._kv_bytes["readback_bytes"] += sum(a.nbytes for a in out)
         return out

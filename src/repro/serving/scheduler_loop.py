@@ -44,6 +44,7 @@ chains unpinned, loop continues for unrelated requests.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -101,6 +102,27 @@ def _gather_rebuild(k, v, pos, lg, idx, fresh):
         jnp.where(fresh[:, None], -1, pos[idx]),
         jnp.where(fresh[:, None], 0, lg[idx]),
     )
+
+
+@functools.partial(jax.jit, static_argnums=3)
+def _take_tail(k, v, i, n):
+    """Row ``i`` (traced) of the batched tail, slots ``[0, n)``: what
+    crosses to the host when one row's written tail is stored, instead of
+    the whole ``[L, W, tail_cap, KV, Dh]`` tail."""
+    return (
+        jax.lax.dynamic_index_in_dim(k[:, :, :n], i, axis=1, keepdims=False),
+        jax.lax.dynamic_index_in_dim(v[:, :, :n], i, axis=1, keepdims=False),
+    )
+
+
+def _tail_rung(t: int, block_size: int, cap: int) -> int:
+    """Slots a read-back of ``t`` written tail slots copies: ``t`` rounded
+    up to a power-of-two multiple of the block size, at most ``cap`` — a
+    few take shapes per tail, each under twice ``t`` past one block."""
+    n = block_size
+    while n < t:
+        n *= 2
+    return min(n, cap)
 
 
 class Row:
@@ -448,23 +470,29 @@ class StepLoop:
             }
             self._logits = glg
         else:
-            old_k = old_v = old_lg = None
+            # only the surviving rows' written slots cross to the host, one
+            # take per row at its own rung, all in one read-back
+            old_lg = None
+            kept: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
             if self._state is not None:
-                old_k, old_v = eng._readback_kv(
-                    self._state["k_tail"], self._state["v_tail"]
-                )
+                picks = [
+                    (old_index[id(r)], r.pos - r.plen)
+                    for r in rows
+                    if id(r) in old_index and r.pos > r.plen
+                ]
+                for (oi, t), (k, v) in zip(picks, self._read_tails(picks)):
+                    kept[oi] = (k[:, :t], v[:, :t])
                 old_lg = np.asarray(self._logits) if self._logits is not None else None
             tails: List[Optional[Dict[str, Any]]] = []
             for r in rows:
-                t = r.pos - r.plen  # written tail slots
                 oi = old_index.get(id(r))
-                if t == 0 or oi is None or old_k is None:
+                if oi not in kept:
                     tails.append(None)
                 else:
                     tails.append(
                         {
-                            "k": old_k[:, oi, :t],
-                            "v": old_v[:, oi, :t],
+                            "k": kept[oi][0],
+                            "v": kept[oi][1],
                             "pos": np.arange(r.plen, r.pos),
                         }
                     )
@@ -491,6 +519,27 @@ class StepLoop:
         # pad rows mirror row 0 while it feeds; once row 0 decodes they
         # freeze at its build-time position (exactly _decode_paged's pads)
         self._pad_pos = rows[0].pos if rows[0].decoding else None
+
+    def _read_tails(
+        self, picks: Sequence[Tuple[int, int]]
+    ) -> List[Tuple[np.ndarray, np.ndarray]]:
+        """The written tail slots of some rows of the batched state, on the
+        host: ``picks`` holds ``(row index, written slots t)`` pairs, and
+        each row's slots ``[0, _tail_rung(t))`` come back as one
+        ``[L, T, KV, Dh]`` pair.  The takes and their copy run in one
+        ``kv_readback`` stage; the folds read no slot past ``t``."""
+        if not picks:
+            return []
+        bs = self.eng.block_size
+
+        def take(k, v):
+            cap = k.shape[2]
+            return [
+                a for i, t in picks for a in _take_tail(k, v, i, _tail_rung(t, bs, cap))
+            ]
+
+        out = self.eng._readback_kv(self._state["k_tail"], self._state["v_tail"], take=take)
+        return list(zip(out[::2], out[1::2]))
 
     # ------------------------------------------------------------ mixed step
     def _mixed_step(self, pages: Tuple[Any, Any]) -> Tuple[int, int]:
@@ -588,11 +637,10 @@ class StepLoop:
                 # freshly computed KV folds back into pool pages along the
                 # radix path: full blocks are cut from the tail, a matched
                 # partial block grows in place (or COWs if shared)
-                tk, tv = eng._readback_kv(self._state["k_tail"], self._state["v_tail"])
+                ((tk, tv),) = self._read_tails([(idx, row.pos - row.plen)])
                 with eng.stage("page_store"):
                     eng._fold_sequence_blocks(
-                        req, req.tokens, tk[:, idx], tv[:, idx], row.plen,
-                        held_blocks=row.blocks,
+                        req, req.tokens, tk, tv, row.plen, held_blocks=row.blocks
                     )
             # the named observation point applies to exact-prefix hits too
             eng._materialize_claims(req, n - n % bs)
@@ -618,10 +666,10 @@ class StepLoop:
             idx = self._members.index(row)
             t = row.pos - row.plen
             if t > 0:
-                tk, tv = eng._readback_kv(self._state["k_tail"], self._state["v_tail"])
+                ((tk, tv),) = self._read_tails([(idx, t)])
                 with eng.stage("page_store"):
                     eng._readmit_decode_tail(
-                        row.req, row.blocks, row.plen, tk[:, idx, :t], tv[:, idx, :t]
+                        row.req, row.blocks, row.plen, tk[:, :t], tv[:, :t]
                     )
         unpin_chain(row.blocks)
         eng._finish_ok(row.req)
