@@ -259,9 +259,9 @@ def run_prefill_ceiling(out_path: Path = Path("results/BENCH_serving.json")):
     - **chunked prefill** (prefill_chunk=C) peaks at S page slots + C
       chunk buffer: the admissible prompt is budget - C.  The cell is
       RUN end to end — the request must finish, peak accounting must fit
-      the budget, and the chunked logits must match the monolithic
-      prefill's logits on the same prompt (greedy argmax equal + allclose
-      at bf16 tolerance).
+      the budget, and the chunked logits must match the logits of the
+      dense engine's monolithic prefill on the same prompt (greedy argmax
+      equal + allclose at bf16 tolerance).
 
     Gate: chunked admissible prompt >= 2x the dense prefill ceiling at
     logits parity.
@@ -280,9 +280,9 @@ def run_prefill_ceiling(out_path: Path = Path("results/BENCH_serving.json")):
     budget = N * bs  # device KV token slots
 
     def mk(mode="paged", **kw):
+        kw.setdefault("cache_len", cache_len)
         return ServingEngine(
-            bundle, params, block_size=bs, device_blocks=N,
-            cache_len=cache_len, decode_mode=mode, **kw,
+            bundle, params, block_size=bs, device_blocks=N, decode_mode=mode, **kw,
         )
 
     # --- dense ceiling: prompt + new must fit the cache shape -------------
@@ -327,10 +327,9 @@ def run_prefill_ceiling(out_path: Path = Path("results/BENCH_serving.json")):
         and eng_c.pool.used <= N
     )
 
-    # --- logits parity vs the monolithic prefill on the same prompt -------
-    # (prefill_chunk=0 is the explicit legacy opt-out now that chunked is
-    # the default graph)
-    lg_full = mk(prefill_chunk=0).prefill_logits(prompt)
+    # --- logits parity vs the dense engine's monolithic prefill ----------
+    # (its cache made large enough for the prompt)
+    lg_full = mk("dense", cache_len=ctx_chunked + new).prefill_logits(prompt)
     lg_chunk = mk(prefill_chunk=chunk).prefill_logits(prompt)
     parity = bool(
         np.allclose(lg_chunk, lg_full, atol=3e-2, rtol=3e-2)

@@ -158,7 +158,7 @@ def phase_serve(eng, rng, vocab: int, on_tpu: bool):
     expect(max_bucket == 4, f"(b) widest prefill bucket had {max_bucket} rows")
     expect(eng.decode_stalls.value() == 0, "(b) decode stalled")
     # the compiled serving steps must carry the Pallas kernels, not jnp
-    state = eng._make_paged_state([[]] * 4, [0] * 4, 8)
+    state = eng._make_paged_state([[]] * 4, [0] * 4, 8, pages=eng._device_pages())
     pos = jnp.zeros((4,), jnp.int32)
     decode_kernel = has_custom_call(eng._jit_paged_decode, eng.params, state, pos, pos)
     chunk = {k: state[k] for k in ("k_pages", "v_pages", "block_tables", "prefix_len")}

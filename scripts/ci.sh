@@ -13,7 +13,7 @@
 #    (>= 2x the dense-assembly ceiling under one device-KV budget, at
 #    equal logits parity), and the chunked-prefill prompt ceiling gate
 #    (>= 2x the dense prefill ceiling under the same budget, at logits
-#    parity with the monolithic prefill), emitting
+#    parity with the dense engine's monolithic prefill), emitting
 #    results/BENCH_serving.json.  The bench exits non-zero if any gate
 #    fails.
 # 3. Runs the seeded chaos campaign (benchmarks/bench_chaos.py): >= 200

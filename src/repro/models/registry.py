@@ -84,10 +84,8 @@ class ModelBundle:
     batch_spec: Callable[[ShapeSpec], Dict[str, jax.ShapeDtypeStruct]]
     cache_spec: Callable[[ShapeSpec], Any]
     # paged serving entry points (transformer families only; None elsewhere):
-    # prefill_collect_fn(params, batch) -> (last-valid logits, k [L,B,S,KV,Dh], v)
     # paged_decode_fn(params, state, tokens, cur_pos) -> (logits, state)
     # prefill_chunk_fn(params, state, tokens, positions) -> (ck, cv) [L,B,C,KV,Dh]
-    prefill_collect_fn: Optional[Callable[..., Any]] = None
     paged_decode_fn: Optional[Callable[..., Any]] = None
     prefill_chunk_fn: Optional[Callable[..., Any]] = None
 
@@ -159,7 +157,6 @@ def build_model(cfg: ModelConfig, mesh=None, moe_strategy: str = "auto") -> Mode
         pre = lambda p, b, cl: lib.prefill(p, cfg, b, cl, mesh=mesh, moe_strategy=moe_strategy)
         dec = lambda p, c, t, pos: lib.decode_step(p, cfg, c, t, pos, mesh=mesh, moe_strategy=moe_strategy)
         mk_cache = lambda b, cl: lib.make_cache(cfg, b, cl)
-        prefill_collect = lambda p, b: lib.prefill_collect(p, cfg, b, mesh=mesh, moe_strategy=moe_strategy)
         paged_dec = lambda p, s, t, pos: lib.paged_decode_step(p, cfg, s, t, pos, mesh=mesh, moe_strategy=moe_strategy)
         prefill_chk = lambda p, s, t, pos: lib.prefill_chunk(p, cfg, s, t, pos, mesh=mesh, moe_strategy=moe_strategy)
 
@@ -184,7 +181,6 @@ def build_model(cfg: ModelConfig, mesh=None, moe_strategy: str = "auto") -> Mode
         # int8 blocks carry no scale sidecar yet; the paged path requires it,
         # so int8 engines stay on the dense decode path
         paged_kw = {
-            "prefill_collect_fn": prefill_collect,
             "paged_decode_fn": paged_dec,
             "prefill_chunk_fn": prefill_chk,
         }

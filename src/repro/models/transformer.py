@@ -238,41 +238,6 @@ def prefill(params, cfg, batch, cache_len: int, *, mesh=None, moe_strategy="auto
     return logits, cache
 
 
-def prefill_collect(params, cfg, batch, *, mesh=None, moe_strategy="auto"):
-    """Batched prefill for the paged serving path.
-
-    Unlike ``prefill`` this returns the FULL-length collected KV
-    [L, B, S, KV, Dh] instead of a dense cache trimmed to ``cache_len`` —
-    the engine slices it into pool pages (full blocks) and a tail (the
-    trailing partial block), so prompt length is bounded by pool pages,
-    not by a per-request cache shape.
-
-    ``batch`` may carry ``valid_len`` [B]: same-bucket prompts are padded on
-    the RIGHT and masked — causal attention already keeps padded positions
-    out of every valid row, so only the logit gather (at valid_len - 1) and
-    the engine-side KV slicing need the true lengths.
-    """
-    tokens = batch["tokens"]
-    extra = batch.get("patch_embeds")
-    B, S = tokens.shape
-    P_len = extra.shape[1] if extra is not None else 0
-    St = S + P_len
-    x = embed_tokens(params, cfg, tokens, extra)
-    positions = jnp.broadcast_to(jnp.arange(St)[None], (B, St))
-    x, _, (ck, cv) = forward_hidden(
-        params, cfg, x, positions, mesh=mesh, moe_strategy=moe_strategy, collect_cache=True
-    )
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    valid_len = batch.get("valid_len")
-    last = (
-        jnp.full((B,), St - 1, jnp.int32)
-        if valid_len is None
-        else valid_len.astype(jnp.int32) + P_len - 1
-    )
-    logits = (x[jnp.arange(B), last] @ unembed(cfg, params)).astype(jnp.float32)
-    return logits, ck, cv
-
-
 def prefill_chunk(params, cfg, state, tokens, positions, *, mesh=None, moe_strategy="auto"):
     """One chunk of chunked paged prefill — the O(chunk) serving path.
 
@@ -288,16 +253,15 @@ def prefill_chunk(params, cfg, state, tokens, positions, *, mesh=None, moe_strat
     Returns the chunk's collected KV ``(ck, cv)`` stacked [L, B, C, KV, Dh]
     — the ONLY KV this launch materializes.  The engine lands each
     completed block directly in a pool page slot and carries the grown
-    block table into the next chunk, so peak prefill memory is O(chunk)
-    instead of the O(S) buffer ``prefill_collect`` returns.  Attention is
-    causal within the chunk and full over the prefix pages (every prefix
-    position precedes every chunk query), which composes to exact causal
-    attention over the whole prompt.
+    block table into the next chunk, so peak prefill memory is O(chunk).
+    Attention is causal within the chunk and full over the prefix pages
+    (every prefix position precedes every chunk query), which composes to
+    exact causal attention over the whole prompt.
 
     Entry state for decode (tail KV + pre-decode logits) intentionally
-    does NOT come from this launch: the engine replays the trailing tokens
-    through the same paged feed executable continuations use, keeping
-    cold-vs-restored parity structural (see serving/engine.py).
+    does NOT come from this launch: the step loop feeds the trailing
+    tokens through the same paged decode launch continuations use, keeping
+    cold-vs-restored parity structural (see serving/scheduler_loop.Row).
     """
     from repro.models.layers import attn_paged_prefill_layer
 

@@ -75,9 +75,8 @@ class Request:
     # measurement surface for the step scheduler and bench_scheduler)
     first_token_ts: Optional[float] = None
     # monotonic wall-clock just before the first launch that carried this
-    # request's tokens (its first prefill chunk or collect launch, or a
-    # continuation's first mixed step): the end of its queue wait, on
-    # first_token_ts's clock
+    # request's tokens (its first prefill chunk, or a continuation's first
+    # mixed step): the end of its queue wait, on first_token_ts's clock
     scheduled_ts: Optional[float] = None
 
 

@@ -23,40 +23,24 @@ ever assembled: a reused or restored block is consumed at its page slot,
 shared prefixes occupy their pages ONCE across the whole batch, and context
 length is bounded by pool pages — not by a per-request cache shape.  Only
 the in-flight tail (trailing partial block + decoded tokens) is per-request
-state.  ``decode_mode="dense"`` keeps the previous gather-to-dense path for
-parity tests and the batch×context ceiling benchmark.
+state.  ``decode_mode="dense"`` keeps the gather-to-dense path as the jnp
+reference, and serves int8-KV and non-transformer bundles, which have no
+paged functions.
 
-**Batched prefill.**  ``run_batch`` groups fresh prompts into same-bucket
-launches (padded to the bucket length and masked by per-row valid lengths),
-so N same-bucket prompts cost ONE prefill compilation/launch instead of N.
-
-**Chunked prefill (``prefill_chunk=``).**  Buckets longer than the chunk
-run chunk-by-chunk: each launch attends already-written pool pages (via
-carried block tables) plus the in-flight chunk (causal), and completed
-blocks land in page slots before the next chunk
-(models/transformer.prefill_chunk; on TPU the Pallas kernel
-kernels/paged_attention.paged_prefill_attention_pallas).  Peak prefill KV
-is O(chunk_len) — the monolithic [L, B, S, KV, Dh] collect buffer never
-exists — so admissible prompt length is bounded by pool pages, not by the
-prefill launch.  Chains stay PINNED across chunks (mid-prefill allocations
-cannot evict a live chain) and a mid-prefill store failure fails closed
-with allocation attribution, exactly like the monolithic path.
-
-**Continuous batching (unified step scheduler).**  ``run_batch`` (paged
-mode) drives the token-budget step loop in ``scheduler_loop.StepLoop``:
-every scheduler step carries ALL live decode/feed rows in one mixed launch
-plus at most one in-flight prefill chunk under ``max_tokens_per_step``,
-waiting requests are admitted/restored between steps, and a request that
-completes mid-stream frees its pages immediately.  Decode rows launch
-every step — admission bursts never stall in-flight decodes behind a full
-prefill.  ``run(req)`` is ``run_batch([req])``; dense mode keeps the
-phased prefill-then-decode path (parity/bench anchor).
-
-``prefill_chunk`` is ON BY DEFAULT (``DEFAULT_PREFILL_CHUNK``): the chunk
-graph is chunk-size-invariant (bitwise — every chunk size stores the same
-page bytes and yields the same entry logits), so chunked-vs-full and
-restored-vs-cold parity is structural.  Pass ``prefill_chunk=0`` for the
-legacy monolithic O(S) collect launch (the ceiling-benchmark anchor).
+**One paged execution path.**  ``run_batch`` and ``prefill_logits`` both
+drive the token-budget step loop in ``scheduler_loop.StepLoop``: every
+scheduler step carries ALL live decode/feed rows in one mixed launch plus
+at most one in-flight prefill chunk under ``max_tokens_per_step``, waiting
+requests are admitted/restored between steps, and a request that completes
+mid-stream frees its pages immediately.  Fresh prompts group into
+same-bucket ``PrefillJob``s that run chunk by chunk
+(models/transformer.prefill_chunk): each launch attends the pages already
+written for its rows plus the in-flight chunk, and completed blocks land
+in page slots before the next chunk, so peak prefill KV is
+O(``prefill_chunk``) and admissible prompt length is bounded by pool pages.
+The chunk graph stores bitwise-identical page bytes for every chunk size,
+so chunk-size and restored-vs-cold parity are structural.  ``run(req)`` is
+``run_batch([req])``.
 
 The engine runs a REAL JAX model: cached/restored page payloads are the
 bytes decode attends over, so a failed restore genuinely leaves the request
@@ -66,7 +50,6 @@ restoration failure — that is the fail-closed semantics).
 from __future__ import annotations
 
 import math
-import time
 from functools import lru_cache, partial
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
@@ -97,7 +80,6 @@ from repro.serving.offload import FailureInjectionConfig, OffloadingConnector
 from repro.serving.scheduler_loop import (
     BATCH_PAD,
     DEFAULT_MAX_TOKENS_PER_STEP,
-    PrefillJob,
     StepLoop,
     _round_up,
 )
@@ -115,11 +97,9 @@ __all__ = [
 ]
 
 # Chunked prefill default (tokens per chunk): O(chunk) peak prefill KV and
-# decode-interleavable prefill launches.  Structural parity makes the flip
-# safe: the chunk graph stores bitwise-identical page bytes for EVERY chunk
-# size (including one chunk covering the whole prompt), so defaulting it on
-# moves no logits surface.  Explicit prefill_chunk=0 restores the monolithic
-# O(S) collect launch.
+# decode-interleavable prefill launches.  The chunk graph stores
+# bitwise-identical page bytes for EVERY chunk size (including one chunk
+# covering the whole prompt), so the size moves no logits surface.
 DEFAULT_PREFILL_CHUNK = 32
 
 # step_scheduled payload fields: KV bytes copied host->device (kv_upload)
@@ -129,15 +109,11 @@ KV_BYTE_FIELDS = ("upload_bytes", "readback_bytes")
 
 @lru_cache(maxsize=16)
 def _jitted_paged_steps(bundle):
-    """Shared jitted paged prefill/decode per bundle (cross-engine cache,
-    like core_engine._jitted_steps)."""
+    """Shared jitted paged decode and prefill chunk per bundle
+    (cross-engine cache, like core_engine._jitted_steps)."""
     if bundle.paged_decode_fn is None:
         return None
-    return (
-        jax.jit(bundle.prefill_collect_fn),
-        jax.jit(bundle.paged_decode_fn),
-        jax.jit(bundle.prefill_chunk_fn),
-    )
+    return jax.jit(bundle.paged_decode_fn), jax.jit(bundle.prefill_chunk_fn)
 
 
 @jax.jit
@@ -193,21 +169,15 @@ class ServingEngine(EngineCore):
             decode_mode = "dense"  # int8 / non-transformer bundles
         self.decode_mode = decode_mode
         if paged is not None:
-            (
-                self._jit_prefill_collect,
-                self._jit_paged_decode,
-                self._jit_prefill_chunk,
-            ) = paged
+            self._jit_paged_decode, self._jit_prefill_chunk = paged
         # prefill_chunk bounds peak prefill KV at O(chunk): every fresh
         # bucket runs chunk-by-chunk, each completed chunk's blocks landing
-        # in pool pages before the next chunk launches.  None -> the
-        # default (chunked ON); explicit 0 -> the legacy single full-length
-        # collect launch.
+        # in pool pages before the next chunk launches
         if prefill_chunk is None:
             prefill_chunk = DEFAULT_PREFILL_CHUNK
-        self.prefill_chunk = (
-            _round_up(prefill_chunk, block_size) if prefill_chunk else 0
-        )
+        if prefill_chunk <= 0:
+            raise ValueError(f"prefill_chunk must be positive, got {prefill_chunk}")
+        self.prefill_chunk = _round_up(prefill_chunk, block_size)
         # unified step-scheduler budget: live rows (1 token each) + at most
         # one prefill chunk (chunk_len x bucket rows) per step
         self.max_tokens_per_step = max_tokens_per_step
@@ -758,17 +728,14 @@ class ServingEngine(EngineCore):
         plens: List[int],
         tail_cap: int,
         tails: Optional[List[Optional[Dict[str, Any]]]] = None,
-        pages: Optional[Tuple[Any, Any]] = None,
+        *,
+        pages: Tuple[Any, Any],
     ) -> Dict[str, Any]:
         """Assemble the jitted paged-decode state: pool pages + per-request
-        block tables + in-flight tails.
-
-        ``pages`` lets run_batch share ONE mirror across every continuation
-        feed in a batch (their stores only add pages no current block table
-        references), instead of re-uploading the pool per request.
-        """
+        block tables + in-flight tails.  ``pages`` is the step's device
+        mirror snapshot (``StepLoop._sync_state``)."""
         B = len(blocks_per_req)
-        jk, jv = pages if pages is not None else self._device_pages()
+        jk, jv = pages
         L, KV, _, page, Dh = jk.shape
         P = _round_up(max((len(bl) for bl in blocks_per_req), default=0), 4)
         bt = np.zeros((B, P), np.int32)
@@ -796,254 +763,6 @@ class ServingEngine(EngineCore):
             "v_tail": v_tail,
             "tail_pos": jnp.asarray(tpos),
         }
-
-    def _paged_entry(self, req: Request, blocks: List[KVBlock], plen: int,
-                     tail_k, tail_v, tail_pos, logits) -> Dict[str, Any]:
-        # blocks arrive PINNED (ref already held by the caller the moment
-        # each block became part of the request's chain); run_batch unpins
-        # after decode
-        return {
-            "req": req,
-            "blocks": blocks,
-            "plen": plen,
-            "tail_k": tail_k,  # [L, t, KV, Dh] numpy (may be empty)
-            "tail_v": tail_v,
-            "tail_pos": tail_pos,  # [t] absolute positions
-            "logits": logits,  # [V]
-            "pos": len(req.tokens),
-        }
-
-    def _continue_paged(
-        self,
-        req: Request,
-        dev_blocks: List[KVBlock],
-        pages: Optional[Tuple[Any, Any]] = None,
-    ) -> Dict[str, Any]:
-        """Prefill-continuation over a (restored) block prefix: feed the
-        uncached tokens through the paged step — reused pages are consumed
-        IN PLACE, nothing is re-assembled or recomputed."""
-        toks = req.tokens
-        n = len(toks)
-        cached = sum(len(b.tokens) for b in dev_blocks)
-        blocks = list(dev_blocks)
-        # pin the chain BEFORE any allocation below: a same-batch store must
-        # not evict a page this request's block table attends
-        pin_chain(blocks)
-        try:
-            if cached == n:
-                # exact-prefix hit: replay the last token through the tail
-                # (its logits pick the first output token) and mask it out
-                # of the page side so the position is not double-counted
-                plen, feed = n - 1, toks[n - 1 :]
-            else:
-                plen, feed = cached, toks[cached:]
-            tail_cap = _round_up(n - plen + req.max_new_tokens, 8)
-            state = self._make_paged_state(
-                [blocks] * BATCH_PAD, [plen] * BATCH_PAD, tail_cap, pages=pages
-            )
-            logits = None
-            for i, tok in enumerate(feed):
-                lg, state = self._jit_paged_decode(
-                    self.params,
-                    state,
-                    jnp.asarray([tok] * BATCH_PAD, jnp.int32),
-                    jnp.asarray([plen + i] * BATCH_PAD, jnp.int32),
-                )
-                logits = lg[0]
-            t_used = n - plen
-            tail_k = np.asarray(state["k_tail"])[:, 0, :t_used]
-            tail_v = np.asarray(state["v_tail"])[:, 0, :t_used]
-            tail_pos = np.arange(plen, n)
-            if cached < n:
-                # freshly computed KV folds back into pool pages along the
-                # radix path: full blocks are cut from the tail, and a
-                # matched partial block grows in place (or COWs if shared)
-                self._fold_sequence_blocks(
-                    req, toks, tail_k, tail_v, plen, held_blocks=blocks
-                )
-            # the named observation point applies to exact-prefix hits too:
-            # a claim accepted after its prefix became resident must still
-            # materialize here (matching the dense path)
-            self._materialize_claims(req, n - n % self.block_size)
-        except BaseException:
-            unpin_chain(blocks)
-            raise
-        return self._paged_entry(req, blocks, plen, tail_k, tail_v, tail_pos, logits)
-
-    def _prefill_bucket(self, reqs: List[Request]) -> List[Dict[str, Any]]:
-        """ONE shared prefill launch for a bucket of fresh prompts: padded to
-        the bucket length, masked by per-row valid lengths.
-
-        When ``prefill_chunk`` is set (the default) EVERY bucket runs
-        through the chunked path — the chunk graph is chunk-size-invariant
-        (one chunk covering the whole prompt is the same computation), so
-        there is exactly ONE default prefill graph and chunked-vs-full
-        parity is structural.  Explicit ``prefill_chunk=0`` keeps this
-        monolithic O(S) collect launch (the ceiling-benchmark anchor)."""
-        B = _round_up(len(reqs), BATCH_PAD)  # padding rows replicate row 0
-        lens = [len(r.tokens) for r in reqs]
-        lens += [lens[0]] * (B - len(reqs))
-        if self.prefill_chunk:
-            return self._prefill_bucket_chunked(reqs, lens, B)
-        S = _round_up(max(lens), self.block_size)
-        tokens = np.zeros((B, S), np.int32)
-        for i in range(B):
-            r = reqs[i] if i < len(reqs) else reqs[0]
-            tokens[i, : len(r.tokens)] = r.tokens
-        with self.stage("prefill"):
-            logits, ck, cv = self._jit_prefill_collect(
-                self.params,
-                {
-                    "tokens": jnp.asarray(tokens),
-                    "valid_len": jnp.asarray(np.asarray(lens, np.int32)),
-                },
-            )
-            jax.block_until_ready(logits)
-        ck = np.asarray(ck)  # [L, B, S, KV, Dh]
-        cv = np.asarray(cv)
-        stored: List[Tuple[Request, List[KVBlock]]] = []
-        for i, req in enumerate(reqs):
-            n = lens[i]
-            try:
-                blocks = self._store_prefix_blocks(req, ck[:, i], cv[:, i], n)
-            except PoolExhausted as e:
-                self._refuse_allocation(req, e)
-                continue
-            self._materialize_claims(req, n - n % self.block_size)
-            stored.append((req, blocks))
-        # Entry state (tail KV + pre-decode logits) comes from the SAME
-        # paged feed the continuation path uses, over the just-stored pages.
-        # A fresh prefill and a later restored continuation of the same
-        # prompt therefore run the SAME executable over bitwise-identical
-        # pages — restored-vs-cold greedy parity is structural, not a
-        # numerical accident of prefill-vs-decode GEMM rounding.
-        entries = []
-        pages = self._device_pages() if stored else None
-        for req, blocks in stored:
-            try:
-                entries.append(self._continue_paged(req, blocks, pages))
-            finally:
-                unpin_chain(blocks)  # release store-time pins; the entry holds its own
-        return entries
-
-    def _prefill_bucket_chunked(
-        self, reqs: List[Request], lens: List[int], B: int
-    ) -> List[Dict[str, Any]]:
-        """Chunked paged prefill for one bucket: the prompt runs CHUNK BY
-        CHUNK through ``prefill_chunk`` — each launch attends the pages
-        already written for its rows (carried block tables, full attention)
-        plus the in-flight chunk (causal), and each completed chunk's
-        blocks land in pool page slots before the next chunk launches.
-
-        Peak prefill KV is O(chunk_len): the monolithic [L, B, S, KV, Dh]
-        collect buffer never exists, so admissible prompt length is bounded
-        by pool pages (the claim substrate), not by what one launch can
-        hold — the last dense-shaped memory cliff in the serving stack.
-
-        Invariants:
-        - chunks are block-aligned and the bucket guarantees every row's
-          full blocks cover every chunk start, so ``prefix_len`` is uniform
-          per chunk and the chunk contract (queries at prefix_len + c)
-          holds for every row;
-        - each row's chain is PINNED as it grows (``pin_chain`` semantics
-          via ``_store_prefix_blocks``): a bucket-mate's store in a later
-          chunk can never evict a page a live block table attends;
-        - a mid-prefill store failure (PoolExhausted) unwinds THAT row's
-          pins and refuses it with allocation attribution
-          (``scheduler_admission_refused`` stage=allocation) — the same
-          ordered claim-scoped outcome the monolithic path yields; bucket
-          mates continue untouched;
-        - claims materialize at ``prefill_complete`` after the final
-          chunk, with metadata bound from the first chunk's stores, and
-          the decode entry (tail + logits) comes from the SAME paged feed
-          executable as continuations (parity stays structural).
-        """
-        # The per-chunk mechanics (carried block tables, per-chunk stores,
-        # pinning, PoolExhausted refusal, launch-failure abort) live in
-        # scheduler_loop.PrefillJob — the SAME object the unified step loop
-        # advances one chunk per step.  Here (prefill_logits / entry-based
-        # callers) the job runs to completion synchronously.
-        bs = self.block_size
-        job = PrefillJob(self, reqs)
-        while not job.done:
-            job.advance()
-        entries = []
-        alive = list(job.alive)
-        pages = self._device_pages() if alive else None
-        for i in alive:
-            req = reqs[i]
-            self._materialize_claims(req, lens[i] - lens[i] % bs)
-            try:
-                entries.append(self._continue_paged(req, job.chains[i], pages))
-            finally:
-                unpin_chain(job.chains[i])  # the entry holds its own pins
-        return entries
-
-    def _prefill_collect_store(
-        self, reqs: List[Request]
-    ) -> List[Tuple[Request, List[KVBlock], int]]:
-        """Step-loop entry for the legacy monolithic collect graph
-        (``prefill_chunk=0``): ONE padded+masked [B, S] launch, stores, and
-        returns (req, pinned_chain, cached_tokens) triples — the step loop
-        feeds/materializes them through the same mixed launches as chunked
-        rows.  PoolExhausted refuses per-row; other launch exceptions
-        propagate for the caller's fail-closed boundary."""
-        B = _round_up(len(reqs), BATCH_PAD)
-        lens = [len(r.tokens) for r in reqs]
-        lens += [lens[0]] * (B - len(reqs))
-        S = _round_up(max(lens), self.block_size)
-        tokens = np.zeros((B, S), np.int32)
-        for i in range(B):
-            r = reqs[i] if i < len(reqs) else reqs[0]
-            tokens[i, : len(r.tokens)] = r.tokens
-        now = time.monotonic()
-        for req in reqs:
-            req.scheduled_ts = now  # the bucket's queue wait ends here
-        with self.stage("prefill"):
-            logits, ck, cv = self._jit_prefill_collect(
-                self.params,
-                {
-                    "tokens": jnp.asarray(tokens),
-                    "valid_len": jnp.asarray(np.asarray(lens, np.int32)),
-                },
-            )
-            jax.block_until_ready(logits)
-        ck, cv = self._readback_kv(ck, cv)
-        stored: List[Tuple[Request, List[KVBlock], int]] = []
-        for i, req in enumerate(reqs):
-            n = lens[i]
-            try:
-                with self.stage("page_store"):
-                    blocks = self._store_prefix_blocks(req, ck[:, i], cv[:, i], n)
-            except PoolExhausted as e:
-                self._refuse_allocation(req, e)
-                continue
-            stored.append((req, blocks, n - n % self.block_size))
-        return stored
-
-    def _decode_paged(self, entries: List[Dict[str, Any]]) -> None:
-        """Paged continuous-batched greedy decode: every step attends each
-        request's pool pages through its block table — shared prefix pages
-        are read in place ONCE for the whole batch."""
-        reqs = [e["req"] for e in entries]
-        tail_cap = _round_up(
-            max(e["pos"] - e["plen"] + e["req"].max_new_tokens for e in entries), 8
-        )
-        # pad to the batch-width bucket (rows replicate entry 0; discarded)
-        pad = [entries[0]] * (_round_up(len(entries), BATCH_PAD) - len(entries))
-        rows = entries + pad
-        state = self._make_paged_state(
-            [e["blocks"] for e in rows],
-            [e["plen"] for e in rows],
-            tail_cap,
-            tails=[
-                {"k": e["tail_k"], "v": e["tail_v"], "pos": e["tail_pos"]}
-                for e in rows
-            ],
-        )
-        logits = jnp.stack([e["logits"] for e in rows])  # [B_pad, V]
-        step = lambda s, t, p: self._jit_paged_decode(self.params, s, t, p)
-        self._greedy_decode_loop(reqs, state, logits, [e["pos"] for e in rows], step)
 
     # ------------------------------------------------------------- dense phase
     def _prepare_dense(self, req: Request, dev_blocks: List[KVBlock]) -> Optional[Dict[str, Any]]:
@@ -1137,26 +856,42 @@ class ServingEngine(EngineCore):
         return self.run_batch([req])[0]
 
     def prefill_logits(self, tokens: Sequence[int], max_new_tokens: int = 1) -> np.ndarray:
-        """Admission + restore + prefill for one request, returning its
-        pre-decode logits [V] as float32 numpy — the comparison surface for
-        parity tests and benches.  Block pins are balanced internally; the
-        request is left un-decoded."""
+        """Serve one request and return its entry logits [V] as float32
+        numpy — the logits its first output token is picked from, the
+        comparison surface for parity tests and benches.
+
+        Paged mode runs the request through the same ``StepLoop`` as
+        ``run_batch`` and takes its row of the launch in which its prompt
+        feed emptied.  The request then finishes like any served request:
+        ``max_new_tokens`` decoded, its decode tail readmitted to the pool,
+        a terminal ``request_finished``.  Dense mode prefills only and
+        leaves the request un-decoded.  Raises ``RuntimeError`` if the
+        request is refused or fails."""
         req = self.submit(tokens, max_new_tokens=max_new_tokens)
-        dev = self._admit_and_restore(req)
-        if dev is None:
-            raise RuntimeError(f"request terminated: {req.status} ({req.error})")
-        if self.decode_mode != "paged":
-            entry = self._prepare_dense(req, dev)
-            return np.asarray(entry["logits"], np.float32)
-        if req.cached_tokens:
-            entry = self._continue_paged(req, dev)
+        entry: List[Any] = []
+        if self.decode_mode == "paged":
+            self._schedule_batch([req])
+            StepLoop(self, [req], on_entry=lambda _req, lg: entry.append(lg)).run()
         else:
-            entries = self._prefill_bucket([req])
-            if not entries:  # refused at the allocation stage
-                raise RuntimeError(f"request terminated: {req.status} ({req.error})")
-            entry = entries[0]
-        unpin_chain(entry["blocks"])
-        return np.asarray(entry["logits"], np.float32)
+            dev = self._admit_and_restore(req)
+            if dev is not None:
+                entry.append(self._prepare_dense(req, dev)["logits"])
+        if req.status in ("refused", "error"):
+            raise RuntimeError(f"request terminated: {req.status} ({req.error})")
+        return np.asarray(entry[0], np.float32)
+
+    def _schedule_batch(self, reqs: List[Request]) -> None:
+        """Batch preamble: the claim-expiry sweep, then ``batch_scheduled``.
+        An expired claim's blocks lose that claim's membership (and its
+        priority boost) but stay resident for their remaining sharers."""
+        self._release_claim_blocks(self.scheduler.sweep_expiry())
+        # uniform for EVERY batch size (including 1): span tracing and
+        # metrics reconciliation never special-case singletons
+        self.events.emit(
+            "batch_scheduled",
+            batch_size=len(reqs),
+            request_ids=[r.request_id for r in reqs],
+        )
 
     def run_batch(self, reqs: Sequence[Request]) -> List[Request]:
         """Continuous batching through the unified token-budget step loop
@@ -1177,21 +912,11 @@ class ServingEngine(EngineCore):
         non-terminal.
         """
         reqs = list(reqs)
-        # --- expiry boundary sweep precedes scheduling; an expired claim's
-        # blocks lose that claim's membership (and its priority boost) but
-        # stay resident for their remaining sharers ---
-        self._release_claim_blocks(self.scheduler.sweep_expiry())
-        # uniform for EVERY batch size (including 1): span tracing and
-        # metrics reconciliation never special-case singletons
-        self.events.emit(
-            "batch_scheduled",
-            batch_size=len(reqs),
-            request_ids=[r.request_id for r in reqs],
-        )
+        self._schedule_batch(reqs)
         if self.decode_mode == "paged":
             StepLoop(self, reqs).run()
             return reqs
-        # --- dense mode: phased prefill-then-decode (parity/bench anchor) ---
+        # --- dense mode: prefill-then-decode (the jnp reference) ---
         entries: List[Dict[str, Any]] = []
         for req in reqs:
             try:

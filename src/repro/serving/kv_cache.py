@@ -133,8 +133,8 @@ class BlockPool:
     snapshots) bypass the page store and own their arrays.
 
     Chunked prefill writes pages AS IT GOES: each completed chunk's blocks
-    land here before the next chunk runs (serving/engine.py,
-    ``_prefill_bucket_chunked``), pinned via ``pin_chain`` so a later
+    land here before the next chunk runs (serving/scheduler_loop.py,
+    ``PrefillJob.advance``), pinned via ``pin_chain`` so a later
     chunk's allocation can never evict a page the growing block table
     attends — the pool is the only resident prefill KV, bounding peak
     prefill memory at O(chunk).

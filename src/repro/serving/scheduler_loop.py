@@ -27,13 +27,6 @@ byte-identical across batch compositions, and
 ``core/analyzer.check_step_interleave_order`` replays any log and rejects
 cross-request reordering of the E0 -> ... -> terminal grammar.
 
-Bitwise launch parity with the phased path (single request, CPU): a lone
-request's chunk launches, feed launches and decode launches carry exactly
-the operands the phased path produced — padding rows replicate row 0 with
-the same token/position choices ``_continue_paged`` and
-``_greedy_decode_loop`` made — so flipping the scheduler does not move any
-logits-parity surface.
-
 Fail-closed hardening (launch boundary): a decode- or prefill-launch
 exception used to escape ``run_batch`` after the ``finally`` unpin and
 strand requests in a non-terminal status.  Here every launch failure is
@@ -129,12 +122,15 @@ class Row:
     """One live request in the step loop.
 
     A row is born from either a restored continuation or a completed
-    prefill job, always with a non-empty ``feed`` queue (the uncached
-    prompt suffix, or the replayed last token on an exact-prefix hit —
-    the same entry rule ``_continue_paged`` applies).  Feed tokens are
-    consumed one per step through the SAME mixed launch as decode; when
-    the queue empties the row's freshly computed full blocks are stored
-    into pool pages and its claims materialize, then greedy decode begins.
+    prefill job, always with a non-empty ``feed`` queue.  The entry rule:
+    the feed is the uncached prompt suffix; on an exact-prefix hit it is
+    the last prompt token, replayed through the tail with the page side
+    masked to ``n - 1`` tokens.  Feed tokens are consumed one per step
+    through the SAME mixed launch as decode; the launch in which the
+    queue empties yields the row's entry logits, its freshly computed
+    full blocks are stored into pool pages and its claims materialize,
+    then greedy decode begins.  A cold prompt and a restored one over the
+    same page bytes thus meet at one feed launch.
 
     ``blocks`` arrives PINNED (the chain's ref was taken when it became
     this request's prefix) and is unpinned exactly once when the row
@@ -173,13 +169,11 @@ class Row:
 class PrefillJob:
     """At most one in-flight chunked prefill bucket.
 
-    Carries the exact per-chunk semantics of the run-to-completion chunked
-    path (``engine._prefill_bucket_chunked``): block-aligned [B, C]
-    launches over carried block tables, per-row stores landing in pool
-    pages between launches, chains pinned as they grow, per-row
-    PoolExhausted refusal with allocation attribution.  The step loop
-    advances it ONE chunk per scheduler step (budget permitting) so decode
-    rows interleave with prefill instead of stalling behind it.
+    Block-aligned [B, C] launches over carried block tables, per-row
+    stores landing in pool pages between launches, chains pinned as they
+    grow, per-row PoolExhausted refusal with allocation attribution.  The
+    step loop advances it ONE chunk per scheduler step (budget permitting)
+    so decode rows interleave with prefill instead of stalling behind it.
     """
 
     def __init__(self, eng, reqs: Sequence[Any]):
@@ -325,11 +319,14 @@ class PrefillJob:
 
 class StepLoop:
     """The unified continuous-batching executor behind ``run_batch``
-    (paged mode).  One instance per run_batch call; requests submitted
-    together enter the waiting queue in order and are admitted FIFO."""
+    (paged mode) and ``prefill_logits``.  One instance per call; requests
+    submitted together enter the waiting queue in order and are admitted
+    FIFO.  ``on_entry(req, logits)``, if given, receives each row's entry
+    logits (its row of the launch in which its feed emptied)."""
 
-    def __init__(self, eng, reqs: Sequence[Any]):
+    def __init__(self, eng, reqs: Sequence[Any], *, on_entry=None):
         self.eng = eng
+        self.on_entry = on_entry
         self.waiting = deque(reqs)
         self.pending_fresh: List[Any] = []  # admitted fresh prompts, FIFO
         self.rows: List[Row] = []
@@ -385,25 +382,7 @@ class StepLoop:
             if _round_up(len(r.tokens), eng.block_size) == key
         ]
         self.pending_fresh = [r for r in self.pending_fresh if r not in bucket]
-        if eng.prefill_chunk:
-            self.job = PrefillJob(eng, bucket)
-        else:
-            # legacy monolithic collect launch (prefill_chunk=0 opt-out):
-            # runs synchronously between steps, unbudgeted — kept for the
-            # O(S) ceiling benchmark and cross-graph parity anchors
-            try:
-                stored = eng._prefill_collect_store(bucket)
-            except Exception as e:  # noqa: BLE001 — launch boundary fails closed
-                for req in bucket:
-                    if req.status == "running":
-                        eng._fail_closed_error(
-                            req,
-                            scope="prefill_collect",
-                            trigger="prefill_launch_failure",
-                            reason=f"{type(e).__name__}: {e}",
-                        )
-                return
-            self.rows.extend(Row(req, blocks, cached) for req, blocks, cached in stored)
+        self.job = PrefillJob(eng, bucket)
 
     # ------------------------------------------------------------ step state
     def _sync_state(self, pages: Tuple[Any, Any]) -> None:
@@ -517,7 +496,7 @@ class StepLoop:
         self._members = list(rows)
         self._tail_cap = tail_cap
         # pad rows mirror row 0 while it feeds; once row 0 decodes they
-        # freeze at its build-time position (exactly _decode_paged's pads)
+        # freeze at its build-time position
         self._pad_pos = rows[0].pos if rows[0].decoding else None
 
     def _read_tails(
@@ -578,9 +557,8 @@ class StepLoop:
                     row.req.first_token_ts = now
                 n_dec += 1
             poss[i] = row.pos
-        # padding rows replicate row 0's launch while it feeds (the
-        # _continue_paged feed form); once row 0 decodes they take their own
-        # argmax at a frozen position (the _greedy_decode_loop pad form)
+        # padding rows replicate row 0's launch while it feeds; once row 0
+        # decodes they take their own argmax at a frozen position
         if row0_feeding:
             toks[len(rows) :] = toks[0]
             poss[len(rows) :] = poss[0]
@@ -619,6 +597,8 @@ class StepLoop:
         # into pool pages and materialize claims (the prefill_complete
         # observation point) before their first decode step
         for i, row in finishing:
+            if self.on_entry is not None:
+                self.on_entry(row.req, lg[i])
             self._finish_feed(i, row)
         # rows that served their final token ride this launch out, then free
         # their pages immediately (mid-stream completion)

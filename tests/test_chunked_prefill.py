@@ -44,24 +44,24 @@ def make_engine(bp, **kw):
 
 @pytest.mark.parametrize("chunk", [8, 16, 32])
 def test_chunked_matches_full_prefill(bp, chunk):
-    """Chunked prefill reproduces the monolithic collect-launch logits
-    across chunk sizes (chunk attention over pages + causal-within-chunk
-    composes to exact causal attention over the whole prompt).  The collect
-    graph is the legacy opt-out now (``prefill_chunk=0``); this cross-graph
-    comparison is tolerance-based — structural equality lives within the
-    chunk graph (see the structural-parity tests below)."""
+    """Chunked prefill reproduces the full-prompt logits of the dense
+    engine's monolithic prefill launch across chunk sizes (chunk attention
+    over pages + causal-within-chunk composes to exact causal attention
+    over the whole prompt).  This cross-graph comparison is
+    tolerance-based — structural equality lives within the chunk graph
+    (see the structural-parity tests below)."""
     prompt = tuple(range(300, 340))  # 40 tokens, bs=4 -> 10 blocks
-    lg_full = make_engine(bp, prefill_chunk=0).prefill_logits(prompt)
+    lg_full = make_engine(bp, decode_mode="dense").prefill_logits(prompt)
     lg_chunk = make_engine(bp, prefill_chunk=chunk).prefill_logits(prompt)
     np.testing.assert_allclose(lg_chunk, lg_full, atol=3e-2, rtol=3e-2)
     assert lg_chunk.argmax() == lg_full.argmax()
 
 
 def test_chunked_matches_full_prefill_unaligned(bp):
-    """A prompt that ends mid-block replays its trailing partial block
-    through the paged tail exactly like the monolithic path."""
+    """A prompt that ends mid-block feeds its trailing partial block
+    through the paged tail and still matches the full-prompt launch."""
     prompt = tuple(range(500, 537))  # 37 tokens: 9 full blocks + 1 partial
-    lg_full = make_engine(bp, prefill_chunk=0).prefill_logits(prompt)
+    lg_full = make_engine(bp, decode_mode="dense").prefill_logits(prompt)
     lg_chunk = make_engine(bp, prefill_chunk=16).prefill_logits(prompt)
     np.testing.assert_allclose(lg_chunk, lg_full, atol=3e-2, rtol=3e-2)
     assert lg_chunk.argmax() == lg_full.argmax()
@@ -109,28 +109,20 @@ def test_restored_vs_cold_structural_parity(bp):
 
 def test_chunk_launch_never_sees_full_prompt(bp):
     """The O(chunk) property, pinned structurally: every prefill launch
-    carries at most chunk_len token positions, and the monolithic collect
-    entry point is never invoked for a long prompt."""
+    of a long prompt carries exactly chunk_len token positions."""
     eng = make_engine(bp, prefill_chunk=16)
-    chunk_shapes, collect_calls = [], []
+    chunk_shapes = []
     orig_chunk = eng._jit_prefill_chunk
-    orig_collect = eng._jit_prefill_collect
 
     def spy_chunk(params, state, tokens, pos):
         chunk_shapes.append(tokens.shape)
         return orig_chunk(params, state, tokens, pos)
 
-    def spy_collect(params, batch):
-        collect_calls.append(batch["tokens"].shape)
-        return orig_collect(params, batch)
-
     eng._jit_prefill_chunk = spy_chunk
-    eng._jit_prefill_collect = spy_collect
     r = eng.submit(tuple(range(100, 148)), max_new_tokens=2)  # 48 tokens
     eng.run(r)
     assert r.status == "finished"
-    assert not collect_calls, "monolithic O(S) collect launch must not run"
-    assert chunk_shapes and all(s[1] == 16 for s in chunk_shapes), chunk_shapes
+    assert chunk_shapes == [(1, 16)] * 3, chunk_shapes
 
 
 def test_prompt_beyond_dense_cache_len_admitted_via_pages(bp):
@@ -151,10 +143,11 @@ def test_prompt_beyond_dense_cache_len_admitted_via_pages(bp):
     blocks = eng.pool.lookup_prefix(long_prompt, 4)
     assert len(blocks) == 50 and all(b.ref == 0 for b in blocks)
     assert validate_event_sequence(eng.events).passed
-    # logits parity with the monolithic collect path on the same prompt
+    # logits parity with the dense engine's full-prompt launch, whose
+    # cache is made large enough for the prompt
     lg_full = ServingEngine(
-        bundle, params, block_size=4, device_blocks=64, cache_len=32,
-        prefill_chunk=0,
+        bundle, params, block_size=4, device_blocks=64, cache_len=256,
+        decode_mode="dense",
     ).prefill_logits(long_prompt)
     lg_chunk = ServingEngine(
         bundle, params, block_size=4, device_blocks=64, cache_len=32,
@@ -162,6 +155,14 @@ def test_prompt_beyond_dense_cache_len_admitted_via_pages(bp):
     ).prefill_logits(long_prompt)
     np.testing.assert_allclose(lg_chunk, lg_full, atol=3e-2, rtol=3e-2)
     assert lg_chunk.argmax() == lg_full.argmax()
+
+
+def test_prefill_chunk_zero_is_rejected(bp):
+    """The chunk graph is the only prefill graph: a chunk size of zero or
+    below is refused at construction, naming the option."""
+    for chunk in (0, -4):
+        with pytest.raises(ValueError, match="prefill_chunk"):
+            make_engine(bp, prefill_chunk=chunk)
 
 
 def test_dense_mode_refuses_beyond_cache_shape(bp):
@@ -304,8 +305,8 @@ def test_chunked_composes_with_bucket_sharing(bp):
 
 
 def test_chunked_batch_tokens_match_full_path(bp):
-    """End-to-end continuous batching over the chunked path emits the same
-    greedy tokens as the monolithic prefill path."""
+    """End-to-end continuous batching emits the same greedy tokens for
+    chunk 16, the default, and one chunk covering the whole prompt."""
     bundle, params = bp
     prompts = [tuple(range(100 + i, 140 + i)) for i in range(3)]
 
@@ -318,5 +319,5 @@ def test_chunked_batch_tokens_match_full_path(bp):
         assert all(r.status == "finished" for r in reqs)
         return [r.output_tokens for r in reqs]
 
-    # chunked (any size, incl. the default) == the legacy monolithic path
-    assert run_all(prefill_chunk=16) == run_all() == run_all(prefill_chunk=0)
+    # 40-token prompts: chunk 64 prefills each in ONE launch
+    assert run_all(prefill_chunk=16) == run_all() == run_all(prefill_chunk=64)

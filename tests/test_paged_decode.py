@@ -18,11 +18,13 @@ from repro.configs import get_config, reduced
 from repro.core.analyzer import (
     check_failure_outcome_path,
     check_observation_path,
+    check_step_interleave_order,
     validate_event_sequence,
 )
 from repro.core.claims import ClaimMode, ClaimState
 from repro.models.registry import build_model
 from repro.serving.engine import ServingEngine
+from repro.serving.scheduler_loop import StepLoop
 from repro.serving.snapshot_engine import SnapshotEngine
 
 PREFIX = tuple(range(10, 26))  # 16 tokens = 4 blocks of 4
@@ -45,7 +47,7 @@ def make_engine(bp, mode="paged", **kw):
 
 
 def _first_logits(eng, tokens, max_new_tokens=2):
-    """Run admission+restore+prefill and return the pre-decode logits."""
+    """Serve the request and return its entry (pre-decode) logits."""
     return eng.prefill_logits(tokens, max_new_tokens=max_new_tokens)
 
 
@@ -176,14 +178,67 @@ def test_padded_prefill_matches_unpadded(bp):
     # same prompt prefilled inside a padded bucket with a longer prompt
     r_s = eng2.submit(short, max_new_tokens=2)
     r_l = eng2.submit(long_, max_new_tokens=2)
-    eng2._admit_and_restore(r_s)
-    eng2._admit_and_restore(r_l)
-    entries = eng2._prefill_bucket([r_s, r_l])
-    for e in entries:
-        for b in e["blocks"]:
-            b.ref -= 1
-    lg_bucket = np.asarray(entries[0]["logits"], np.float32)  # row of the shared launch
+    shapes, entry = [], {}
+    orig = eng2._jit_prefill_chunk
+
+    def spy(params, state, toks, pos):
+        shapes.append(tuple(toks.shape))
+        return orig(params, state, toks, pos)
+
+    eng2._jit_prefill_chunk = spy
+    StepLoop(
+        eng2, [r_s, r_l],
+        on_entry=lambda req, lg: entry.setdefault(req.request_id, np.asarray(lg, np.float32)),
+    ).run()
+    assert r_s.status == r_l.status == "finished"
+    assert shapes and all(s[0] == 4 for s in shapes), shapes  # one padded bucket
+    lg_bucket = entry[r_s.request_id]  # row of the shared launches
     np.testing.assert_allclose(lg_bucket, lg_solo, atol=3e-2, rtol=3e-2)
+
+
+def _spy_launches(eng):
+    """Record the name of every model launch the engine makes."""
+    names = []
+    for attr in ("_jit_prefill", "_jit_decode", "_jit_paged_decode", "_jit_prefill_chunk"):
+        orig = getattr(eng, attr)
+
+        def spy(*a, _orig=orig, _name=attr):
+            names.append(_name)
+            return _orig(*a)
+
+        setattr(eng, attr, spy)
+    return names
+
+
+def test_prefill_logits_is_the_step_loop_entry(bp):
+    """``prefill_logits`` is the step loop's entry: its argmax is the first
+    token ``run`` serves on a fresh engine, cold and after an
+    offload/restore, and it launches only the step loop's two programs."""
+    prompt = PREFIX + (40, 41, 42)
+    cold = make_engine(bp)
+    launches = _spy_launches(cold)
+    lg_cold = cold.prefill_logits(prompt)
+    assert set(launches) == {"_jit_paged_decode", "_jit_prefill_chunk"}, launches
+    fresh = make_engine(bp)
+    want = fresh.run(fresh.submit(prompt, max_new_tokens=2)).output_tokens[0]
+    assert int(lg_cold.argmax()) == want
+
+    eng = make_engine(bp)
+    claim = eng.accept_claim(PREFIX, ClaimMode.OFFLOADABLE)
+    eng.run(eng.submit(PREFIX + (30, 31), max_new_tokens=1))
+    assert eng.offload_claim(claim.claim_id, tier="host")
+    launches = _spy_launches(eng)
+    lg_restored = eng.prefill_logits(prompt)
+    assert claim.state == ClaimState.RESTORED
+    assert set(launches) == {"_jit_paged_decode"}, launches
+    assert int(lg_restored.argmax()) == want
+    # a refused request has no entry logits: prefill_logits raises
+    assert eng.offload_claim(claim.claim_id, tier="host")
+    eng.connector.injection.resident_claim_load_failure = True
+    eng.connector.injection.fail_claim_id = claim.claim_id
+    with pytest.raises(RuntimeError, match="refused"):
+        eng.prefill_logits(prompt)
+    assert check_step_interleave_order(eng.events).passed
 
 
 def test_exact_prefix_hit_still_materializes(bp):

@@ -97,8 +97,8 @@ def test_multi_turn_reuse_shares_pages_and_logits(bp):
     assert validate_event_sequence(eng.events).passed
     assert check_shared_page_immutability(eng.events).passed
     assert check_metrics_reconcile(eng.events, eng.metrics).passed
-    # the prefill_logits probe leaves its request un-decoded
-    assert check_step_interleave_order(eng.events, require_terminal=False).passed
+    # the prefill_logits probe's request finishes like any served request
+    assert check_step_interleave_order(eng.events).passed
 
 
 def test_no_sharing_baseline_isolates_requests(bp):
