@@ -84,7 +84,7 @@ class ModelBundle:
     batch_spec: Callable[[ShapeSpec], Dict[str, jax.ShapeDtypeStruct]]
     cache_spec: Callable[[ShapeSpec], Any]
     # paged serving entry points (transformer families only; None elsewhere):
-    # paged_decode_fn(params, state, tokens, cur_pos) -> (logits, state)
+    # paged_decode_fn(params, state, tokens, cur_pos) -> (logits, {k_tail, v_tail, tail_pos})
     # prefill_chunk_fn(params, state, tokens, positions) -> (ck, cv) [L,B,C,KV,Dh]
     paged_decode_fn: Optional[Callable[..., Any]] = None
     prefill_chunk_fn: Optional[Callable[..., Any]] = None

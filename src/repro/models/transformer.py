@@ -305,10 +305,15 @@ def paged_decode_step(params, cfg, state, tokens, cur_pos, *, mesh=None, moe_str
       prefix_len      [B] int32             tokens addressed via the table
       k_tail/v_tail   [L, B, T, KV, Dh]     in-flight tail (written here)
       tail_pos        [B, T] int32          absolute tail positions (-1 empty)
-    tokens, cur_pos: [B].  Returns (logits [B, V], state with updated tail).
+    tokens, cur_pos: [B].  Returns (logits [B, V], {k_tail, v_tail,
+    tail_pos}): the updated tail only.
 
     The page pool is never rewritten: a step only appends one (k, v) row to
     the tail at ``cur_pos - prefix_len`` and attends pages + tail in place.
+    The pool, ``block_tables`` and ``prefix_len`` are read-only inputs and
+    never leave the program: under a non-donating ``jax.jit`` an input
+    passed back out is a fresh output buffer, so returning the pool would
+    copy both whole pools on every launch.  The caller keeps them.
 
     On TPU the batch rides the paged-attention kernel's grid.  On the host
     CPU backend the rows run through ``lax.map`` instead: XLA:CPU's
@@ -352,13 +357,11 @@ def paged_decode_step(params, cfg, state, tokens, cur_pos, *, mesh=None, moe_str
             "pos": cur_pos,
         }
         out = jax.lax.map(row_fn, rows)
-        new_state = dict(
-            state,
-            k_tail=jnp.moveaxis(out["tk"], 0, 1),
-            v_tail=jnp.moveaxis(out["tv"], 0, 1),
-            tail_pos=out["tpos"],
-        )
-        return out["lg"], new_state
+        return out["lg"], {
+            "k_tail": jnp.moveaxis(out["tk"], 0, 1),
+            "v_tail": jnp.moveaxis(out["tv"], 0, 1),
+            "tail_pos": out["tpos"],
+        }
 
     from repro.models.layers import attn_paged_decode_layer, slot_update as _slot_update
 
@@ -400,8 +403,7 @@ def paged_decode_step(params, cfg, state, tokens, cur_pos, *, mesh=None, moe_str
     with jax.named_scope("head"):
         x = apply_norm(cfg.norm, params["final_norm"], x)
         logits = (x[:, 0] @ unembed(cfg, params)).astype(jnp.float32)
-    new_state = dict(state, k_tail=ntk, v_tail=ntv, tail_pos=tail_pos)
-    return logits, new_state
+    return logits, {"k_tail": ntk, "v_tail": ntv, "tail_pos": tail_pos}
 
 
 def decode_step(params, cfg, cache, tokens, cur_pos, *, mesh=None, moe_strategy="auto"):

@@ -589,7 +589,9 @@ class StepLoop:
             self._logits = None
             self._members = []
             return (n_dec, n_feed)
-        self._state = state
+        # the launch returns the new tail; the pool and tables stay this
+        # state's own (the mirror's arrays, which _sync_state swaps)
+        self._state = {**self._state, **state}
         self._logits = lg
         for row in rows:
             row.pos += 1

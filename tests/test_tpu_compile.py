@@ -11,6 +11,8 @@ one process at a time may load the TPU library, and every test worker
 imports this file.  The persistent compilation cache is off around the
 compiles (an entry written without a chip cannot be read back).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -83,13 +85,13 @@ def _block_copy(s):
     return kv_block_copy_pallas, args
 
 
-def _decode_step(s):
+def _decode_step(s, n=64):
     bundle = build_model(CFG)
     params = jax.tree.map(
         lambda x: s(x.shape, x.dtype),
         jax.eval_shape(bundle.init_params, jax.random.PRNGKey(0)),
     )
-    L, W, n = CFG.num_layers, 4, 64
+    L, W = CFG.num_layers, 4
     state = {
         "k_pages": s((L, KV, n, PAGE, D)),
         "v_pages": s((L, KV, n, PAGE, D)),
@@ -123,3 +125,25 @@ def test_serving_path_compiles_for_v5e(one_chip, monkeypatch, case):
     fn, args = CASES[case](_spec(one_chip))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# the claim_docs cell's pool: at 64 pages the compiler stages the whole pool
+# into another memory space (a pool-shaped ``ConcatBitcast``), at the cells'
+# sizes it reads the pool where it lies
+CELL_PAGES = 1414
+
+
+def test_decode_step_passes_no_pool_through_for_v5e(one_chip, monkeypatch):
+    # an input returned from the non-donating jit leaves it as a fresh
+    # buffer: a pool returned would be copied, K and V, on every launch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = _decode_step(_spec(one_chip), n=CELL_PAGES)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    pool = re.escape("bf16[" + ",".join(map(str, args[1]["k_pages"].shape)) + "]")
+    ops = re.findall(rf"= {pool}\{{[^}}]*\}} ([\w-]+)\(", hlo)
+    # read in place: the pool enters as a parameter (the scan's loop
+    # carries it), and no instruction writes a pool-shaped result
+    assert ops and set(ops) <= {"parameter", "get-tuple-element"}, sorted(set(ops))
+    entry = hlo[hlo.index("\nENTRY ") :]
+    root = next(line for line in entry.splitlines() if line.lstrip().startswith("ROOT "))
+    assert not re.search(pool, root), root[:300]
